@@ -49,6 +49,8 @@ ALL = ("table3", "table4", "table5", "table6", "accuracy", "kernels",
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = sys.argv[1:] or ALL
     os.makedirs(RESULTS_DIR, exist_ok=True)
     csv = ["name,us_per_call,derived"]

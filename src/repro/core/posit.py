@@ -342,9 +342,6 @@ def matmul_exact(a_codes, b_codes, fmt: PositFormat):
     return dot_exact(a_codes[:, None, :], jnp.swapaxes(b_codes, 0, 1)[None, :, :], fmt)
 
 
-# public aliases for kernel code (Pallas bodies reuse the same bit helpers)
-mask_u32, shl_u32, shr_u32, negate_code_u32 = _mask, _shl, _shr, _negate_code
-
 # convenience jitted entry points ------------------------------------------
 
 decode_to_f32_jit = jax.jit(decode_to_f32, static_argnums=1)

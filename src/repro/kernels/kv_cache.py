@@ -7,9 +7,10 @@ module stores the attention K/V rings as posit codes with a per-row
   write path  ``kv_append``       — one token's K/V rows are scaled,
       RNE-encoded and stored straight into the ring at ``pos % W``.  The
       ring position is a scalar-prefetch operand, so only the written
-      (1, hd) row blocks ever move between HBM and VMEM (no full-ring
-      read-modify-write), and the cache buffers are donated via
-      ``input_output_aliases``.
+      (H, Dc) code rows move from VMEM to HBM (no full-ring
+      read-modify-write), and the code buffers are donated via
+      ``input_output_aliases``; the per-row scales go through an XLA
+      scatter.
   read path   ``decode_attention`` — fused decode-on-read flash decode:
       posit K/V tiles are decoded to f32 *in VMEM* right before the
       online-softmax inner loop (grid innermost over KV blocks, (m, l,
@@ -42,7 +43,6 @@ from .posit_decode import decode_tile
 from .posit_encode import encode_tile
 
 NEG_INF = -1e30
-U32 = jnp.uint32
 
 
 # ---------------------------------------------------------------------------
@@ -75,17 +75,34 @@ def unpack_nibbles(packed):
     return jnp.concatenate([packed & 0xF, packed >> 4], axis=-1)
 
 
+def scale_rows(x):
+    """Float rows (..., hd) -> (rows / scale, scale (...) f32): the XLA half
+    of an append.  The kernels store scales through an XLA scatter because
+    a (1, nkv) f32 row of a lane-padded (.., nkv) array is neither a legal
+    Mosaic block nor a legal DMA window."""
+    x = x.astype(jnp.float32)
+    scale = row_pow2_scale(x)
+    return x / scale, scale[..., 0]
+
+
+def encode_scaled_rows(x, fmt: PositFormat, packed: bool):
+    """Rows already divided by their pow2 scale -> codes (the kernel-body
+    half of an append).  Nibble packing happens on int32 lanes and the
+    result is narrowed to the storage dtype last, as Mosaic asks."""
+    if not packed:
+        return encode_tile(x, fmt)
+    codes = encode_tile(x, fmt).astype(jnp.int32)
+    return pack_nibbles(codes).astype(fmt.storage_dtype)
+
+
 def encode_kv_rows(x, fmt: PositFormat, packed: bool = False):
     """Float rows (..., hd) -> (codes, scale (..., 1) f32).
 
     Per-row pow2 scale centres the posit tapered-precision region on the
     row's magnitude; codes are bit-exact RNE posit.  ``packed`` nibble-packs
     4-bit codes (hd must be even)."""
-    scale = row_pow2_scale(x)
-    codes = encode_tile(x.astype(jnp.float32) / scale, fmt)
-    if packed:
-        codes = pack_nibbles(codes)
-    return codes, scale
+    xs, scale = scale_rows(x)
+    return encode_scaled_rows(xs, fmt, packed), scale[..., None]
 
 
 def decode_kv_rows(codes, scale, fmt: PositFormat, packed: bool = False,
@@ -134,16 +151,11 @@ def kv_append_ref(k_codes, k_scale, v_codes, v_scale, k_new, v_new, pos,
 # kv_append_rows: encode-on-write ring update for a T-token chunk (Pallas)
 # ---------------------------------------------------------------------------
 
-def _append_rows_kernel(idx_ref, kn_ref, vn_ref, kc_ref, ks_ref, vc_ref,
-                        vs_ref, kco_ref, kso_ref, vco_ref, vso_ref, *,
-                        fmt, packed):
-    del idx_ref, kc_ref, ks_ref, vc_ref, vs_ref  # rows consumed by specs
-    kc, ks = encode_kv_rows(kn_ref[0, 0, 0], fmt, packed)
-    vc, vs = encode_kv_rows(vn_ref[0, 0, 0], fmt, packed)
-    kco_ref[0, 0, 0] = kc
-    vco_ref[0, 0, 0] = vc
-    kso_ref[0, 0, 0] = ks[0]
-    vso_ref[0, 0, 0] = vs[0]
+def _append_rows_kernel(idx_ref, kn_ref, vn_ref, kc_ref, vc_ref, kco_ref,
+                        vco_ref, *, fmt, packed):
+    del idx_ref, kc_ref, vc_ref  # the row address is consumed by the specs
+    kco_ref[...] = encode_scaled_rows(kn_ref[...], fmt, packed)
+    vco_ref[...] = encode_scaled_rows(vn_ref[...], fmt, packed)
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "packed", "interpret"))
@@ -155,46 +167,36 @@ def kv_append_rows(k_codes, k_scale, v_codes, v_scale, k_new, v_new, pos,
     k/v_new are (B, T, H, hd) floats and ``pos`` is the (B,) per-slot start
     position — token t of slot b lands at ring index (pos[b] + t) mod W.
     The (B, T) index matrix is a scalar-prefetch operand, so only the
-    written (1, hd) row blocks move between HBM and VMEM and the cache
-    buffers are donated, exactly like the single-row kernel."""
+    written (H, Dc) code rows move from VMEM to HBM and the code buffers
+    are donated.  Each block spans all heads of a row, so its trailing
+    (H, Dc) dims are whole, as the TPU tiling rule asks."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     b, w, h, dc = k_codes.shape
     t, hd = k_new.shape[1], k_new.shape[-1]
     idx = (jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))[:, None]
            + jnp.arange(t, dtype=jnp.int32)[None, :]) % w
+    kx, ks = scale_rows(k_new)
+    vx, vs = scale_rows(v_new)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, t, h),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, hd), lambda i, ti, j, s: (i, ti, j, 0)),
-            pl.BlockSpec((1, 1, 1, hd), lambda i, ti, j, s: (i, ti, j, 0)),
-            pl.BlockSpec((1, 1, 1, dc), lambda i, ti, j, s: (i, s[i, ti], j, 0)),
-            pl.BlockSpec((1, 1, 1), lambda i, ti, j, s: (i, s[i, ti], j)),
-            pl.BlockSpec((1, 1, 1, dc), lambda i, ti, j, s: (i, s[i, ti], j, 0)),
-            pl.BlockSpec((1, 1, 1), lambda i, ti, j, s: (i, s[i, ti], j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, dc), lambda i, ti, j, s: (i, s[i, ti], j, 0)),
-            pl.BlockSpec((1, 1, 1), lambda i, ti, j, s: (i, s[i, ti], j)),
-            pl.BlockSpec((1, 1, 1, dc), lambda i, ti, j, s: (i, s[i, ti], j, 0)),
-            pl.BlockSpec((1, 1, 1), lambda i, ti, j, s: (i, s[i, ti], j)),
-        ],
-    )
-    return pl.pallas_call(
+    sq = pl.Squeezed()
+    row = pl.BlockSpec((sq, sq, h, dc), lambda i, ti, s: (i, s[i, ti], 0, 0))
+    new = pl.BlockSpec((sq, sq, h, hd), lambda i, ti, s: (i, ti, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    kc, vc = pl.pallas_call(
         functools.partial(_append_rows_kernel, fmt=fmt, packed=packed),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_codes.shape, k_codes.dtype),
-            jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
-            jax.ShapeDtypeStruct(v_codes.shape, v_codes.dtype),
-            jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, t),
+            in_specs=[new, new, hbm, hbm], out_specs=[row, row]),
+        out_shape=[jax.ShapeDtypeStruct(k_codes.shape, k_codes.dtype),
+                   jax.ShapeDtypeStruct(v_codes.shape, v_codes.dtype)],
         # operand indices include the scalar-prefetch arg (index 0)
-        input_output_aliases={3: 0, 4: 1, 5: 2, 6: 3},
+        input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
-    )(idx, k_new, v_new, k_codes, k_scale, v_codes, v_scale)
+    )(idx, kx, vx, k_codes, v_codes)
+    rows = jnp.arange(b)[:, None]
+    return (kc, k_scale.at[rows, idx].set(ks), vc,
+            v_scale.at[rows, idx].set(vs))
 
 
 def kv_append_rows_ref(k_codes, k_scale, v_codes, v_scale, k_new, v_new, pos,
@@ -221,37 +223,69 @@ def kv_append_rows_ref(k_codes, k_scale, v_codes, v_scale, k_new, v_new, pos,
 # decode_attention: fused decode-on-read flash decode (Pallas)
 # ---------------------------------------------------------------------------
 
-def _decode_attn_kernel(len_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
-                        o_ref, m_ref, l_ref, acc_ref, *, fmt, packed, bw, nw):
-    ri = pl.program_id(0)          # fused (batch x kv-head) row
-    wi = pl.program_id(1)
+def flash_block(q_ref, kc_ref, ks_ref, vc_ref, vs_ref, m_ref, l_ref,
+                 acc_ref, first, n_valid, *, fmt, packed):
+    """One KV block of the online softmax, for every KV head of a row.
 
-    @pl.when(wi == 0)
+    kc/vc refs: (rows, nkv, Dc) codes; ks/vs refs: (rows, nkv) scales;
+    q_ref: (nkv, grp, hd).  Keys at block offsets >= ``n_valid`` are
+    masked (their V rows are zeroed too, so stale or padded codes cannot
+    leak a NaN into the sum).  Decode-on-read: each head's posit codes
+    become f32 in VMEM right before the MXU consumes them."""
+    nkv = q_ref.shape[0]
+    rows = kc_ref.shape[0]
+
+    @pl.when(first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # decode-on-read: posit codes -> f32 in VMEM, right before the MACs
-    k = decode_tile(unpack_nibbles(kc_ref[0]) if packed else kc_ref[0],
-                    fmt, jnp.float32) * ks_ref[0][:, None]       # (bw, hd)
-    v = decode_tile(unpack_nibbles(vc_ref[0]) if packed else vc_ref[0],
-                    fmt, jnp.float32) * vs_ref[0][:, None]
-    q = q_ref[0].astype(jnp.float32)                              # (grp, hd)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)       # (grp, bw)
-    kpos = wi * bw + jnp.arange(bw)
-    s = jnp.where((kpos < len_ref[ri])[None, :], s, NEG_INF)
-    m_new = jnp.maximum(m_ref[...], s.max(-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_ref[...] - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    col_ok = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1) < n_valid
+    row_ok = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) < n_valid
+    for j in range(nkv):
+        kc = kc_ref[:, j, :].astype(jnp.int32)                    # (rows, Dc)
+        vc = vc_ref[:, j, :].astype(jnp.int32)
+        if packed:
+            kc, vc = unpack_nibbles(kc), unpack_nibbles(vc)
+        k = decode_tile(kc, fmt) * ks_ref[:, pl.ds(j, 1)]         # (rows, hd)
+        v = decode_tile(vc, fmt) * vs_ref[:, pl.ds(j, 1)]
+        v = jnp.where(row_ok, v, 0.0)
+        q = q_ref[j].astype(jnp.float32)                          # (grp, hd)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(col_ok, s, NEG_INF)                         # (grp, rows)
+        m_prev = m_ref[j]
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[j] = l_ref[j] * corr + p.sum(-1, keepdims=True)
+        acc_ref[j] = acc_ref[j] * corr + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_ref[j] = m_new
+
+
+def flash_finish(o_ref, l_ref, acc_ref):
+    o_ref[...] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def flash_scratch(nkv: int, grp: int, hd: int):
+    return [pltpu.VMEM((nkv, grp, 1), jnp.float32),
+            pltpu.VMEM((nkv, grp, 1), jnp.float32),
+            pltpu.VMEM((nkv, grp, hd), jnp.float32)]
+
+
+def _decode_attn_kernel(len_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
+                        o_ref, m_ref, l_ref, acc_ref, *, fmt, packed, bw, nw):
+    bi = pl.program_id(0)
+    wi = pl.program_id(1)
+    flash_block(q_ref, kc_ref, ks_ref, vc_ref, vs_ref, m_ref, l_ref,
+                 acc_ref, wi == 0,
+                 len_ref[bi] - wi * bw, fmt=fmt, packed=packed)
 
     @pl.when(wi == nw - 1)
     def _finish():
-        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        flash_finish(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "packed", "block_w",
@@ -263,50 +297,35 @@ def decode_attention(q, k_codes, k_scale, v_codes, v_scale, cache_len,
 
     q: (B, 1, nh, hd); k/v_codes: (B, W, nkv, Dc); k/v_scale: (B, W, nkv);
     cache_len: count of valid ring entries, scalar (shared) or (B,)
-    per-slot.  Online softmax over KV blocks of ``block_w`` with
-    decode-in-VMEM.  Returns (B, 1, nh, hd)."""
+    per-slot.  The grid walks (slot, KV block); each step reads one
+    (block_w, nkv, Dc) block of the ring in place, all KV heads at once,
+    and runs the online softmax with decode-in-VMEM.  On the TPU
+    ``block_w`` must divide by 8 (or equal W).  Returns (B, 1, nh, hd)."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     b, w, nkv, dc = k_codes.shape
     nh, hd = q.shape[2], q.shape[3]
     grp = nh // nkv
     bw = min(block_w, w)
-    pw = -w % bw
-    # relayout to (B*nkv, ...) rows; the pad region is masked by cache_len<=W
-    qg = (q.reshape(b, nkv, grp, hd) * (hd ** -0.5)).reshape(b * nkv, grp, hd)
-
-    def rows(codes, scale):
-        c = jnp.transpose(codes, (0, 2, 1, 3)).reshape(b * nkv, w, dc)
-        s = jnp.transpose(scale, (0, 2, 1)).reshape(b * nkv, w)
-        if pw:
-            c = jnp.pad(c, ((0, 0), (0, pw), (0, 0)))
-            s = jnp.pad(s, ((0, 0), (0, pw)), constant_values=1.0)
-        return c, s
-
-    kc, ks = rows(k_codes, k_scale)
-    vc, vs = rows(v_codes, v_scale)
-    nw = kc.shape[1] // bw
+    nw = -(-w // bw)
+    qg = (q.reshape(b, nkv, grp, hd) * (hd ** -0.5)).astype(jnp.float32)
+    lens = jnp.minimum(
+        jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,)), w)
+    sq = pl.Squeezed()
+    codes = pl.BlockSpec((sq, bw, nkv, dc), lambda i, wi, ln: (i, wi, 0, 0))
+    scales = pl.BlockSpec((sq, bw, nkv), lambda i, wi, ln: (i, wi, 0))
+    heads = pl.BlockSpec((sq, nkv, grp, hd), lambda i, wi, ln: (i, 0, 0, 0))
     out = pl.pallas_call(
         functools.partial(_decode_attn_kernel, fmt=fmt, packed=packed,
                           bw=bw, nw=nw),
-        grid=(b * nkv, nw),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, grp, hd), lambda i, wi: (i, 0, 0)),
-            pl.BlockSpec((1, bw, dc), lambda i, wi: (i, wi, 0)),
-            pl.BlockSpec((1, bw), lambda i, wi: (i, wi)),
-            pl.BlockSpec((1, bw, dc), lambda i, wi: (i, wi, 0)),
-            pl.BlockSpec((1, bw), lambda i, wi: (i, wi)),
-        ],
-        out_specs=pl.BlockSpec((1, grp, hd), lambda i, wi: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * nkv, grp, hd), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((grp, 1), jnp.float32),
-                        pltpu.VMEM((grp, 1), jnp.float32),
-                        pltpu.VMEM((grp, hd), jnp.float32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, nw),
+            in_specs=[heads, codes, scales, codes, scales],
+            out_specs=heads, scratch_shapes=flash_scratch(nkv, grp, hd)),
+        out_shape=jax.ShapeDtypeStruct((b, nkv, grp, hd), jnp.float32),
         interpret=interpret,
-    )(jnp.repeat(jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,)),
-                 nkv), qg, kc, ks, vc, vs)
-    return out.reshape(b, nkv, grp, hd).reshape(b, 1, nh, hd).astype(q.dtype)
+    )(lens, qg, k_codes, k_scale, v_codes, v_scale)
+    return out.reshape(b, 1, nh, hd).astype(q.dtype)
 
 
 def decode_attention_ref(q, k_codes, k_scale, v_codes, v_scale, cache_len,

@@ -20,13 +20,14 @@ Layout (per attention layer; no batch axis — pages are shared):
   write path  ``paged_kv_append``     — the destination flat row
       (table[b, pos//ps] * ps + pos%ps) is computed outside and handed to
       the kernel as a scalar-prefetch vector, so only the written
-      (1, Dc) row blocks move between HBM and VMEM and the pool buffers
-      are donated (``input_output_aliases``), exactly like the ring
-      ``kv_append``.
+      (nkv, Dc) code rows move from VMEM to HBM and the code buffers are
+      donated (``input_output_aliases``), exactly like the ring
+      ``kv_append``; the scales go through an XLA scatter.
   read path   ``paged_decode_attention`` — the grid's innermost dim walks
       the sequence's page list: the page-table row is scalar-prefetched
-      and the *index map* uses it to DMA physical pages into VMEM, where
-      posit tiles are decoded right before the online-softmax MACs.
+      and the *index map* uses it to DMA whole (ps, nkv, Dc) physical
+      pages into VMEM, where posit tiles are decoded right before the
+      online-softmax MACs.
       (m, l, acc) live in VMEM scratch across the page walk.
 
 Pure-jnp references (``paged_kv_append_ref`` / ``paged_decode_attention_ref``
@@ -45,9 +46,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import PositFormat
-from .kv_cache import (NEG_INF, decode_kv_rows, encode_kv_rows,
-                       unpack_nibbles)
-from .posit_decode import decode_tile
+from .kv_cache import (decode_kv_rows, encode_kv_rows, encode_scaled_rows,
+                       flash_block, flash_finish, flash_scratch, scale_rows)
 
 
 def flat_dst_rows(page_table, pos, page_size: int):
@@ -107,16 +107,11 @@ def paged_kv_append_ref(k_codes, k_scale, v_codes, v_scale, k_new, v_new,
 # paged_kv_append_rows: chunked encode-on-write into pool rows (Pallas)
 # ---------------------------------------------------------------------------
 
-def _paged_append_rows_kernel(dst_ref, kn_ref, vn_ref, kc_ref, ks_ref,
-                              vc_ref, vs_ref, kco_ref, kso_ref, vco_ref,
-                              vso_ref, *, fmt, packed):
-    del dst_ref, kc_ref, ks_ref, vc_ref, vs_ref  # rows consumed by the specs
-    kc, ks = encode_kv_rows(kn_ref[0, 0, 0], fmt, packed)
-    vc, vs = encode_kv_rows(vn_ref[0, 0, 0], fmt, packed)
-    kco_ref[0, 0] = kc
-    vco_ref[0, 0] = vc
-    kso_ref[0, 0] = ks[0]
-    vso_ref[0, 0] = vs[0]
+def _paged_append_rows_kernel(dst_ref, kn_ref, vn_ref, kc_ref, vc_ref,
+                              kco_ref, vco_ref, *, fmt, packed):
+    del dst_ref, kc_ref, vc_ref  # the row address is consumed by the specs
+    kco_ref[...] = encode_scaled_rows(kn_ref[...], fmt, packed)
+    vco_ref[...] = encode_scaled_rows(vn_ref[...], fmt, packed)
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "packed", "interpret"))
@@ -127,46 +122,37 @@ def paged_kv_append_rows(k_codes, k_scale, v_codes, v_scale, k_new, v_new,
 
     Generalizes ``paged_kv_append`` from one row to T rows per slot:
     k/v_new are (B, T, nkv, hd) floats and ``dst`` is the (B, T) flat-row
-    matrix from ``flat_dst_rows_chunk``.  Live slots never share rows;
-    idle slots may collide on the trash page, where the sequential grid
-    makes the last write win — benign garbage either way."""
+    matrix from ``flat_dst_rows_chunk``.  Each block is one whole
+    (nkv, Dc) pool row, the codes go through the kernel and the per-row
+    scales through an XLA scatter (``kv_cache.scale_rows``).  Live slots
+    never share rows; idle slots may collide on the trash page, where the
+    last write wins — benign garbage either way."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     b, t, h, hd = k_new.shape
     dc = k_codes.shape[-1]
     dst = jnp.asarray(dst, jnp.int32).reshape(b, t)
+    kx, ks = scale_rows(k_new)
+    vx, vs = scale_rows(v_new)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, t, h),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, hd), lambda i, ti, j, s: (i, ti, j, 0)),
-            pl.BlockSpec((1, 1, 1, hd), lambda i, ti, j, s: (i, ti, j, 0)),
-            pl.BlockSpec((1, 1, dc), lambda i, ti, j, s: (s[i, ti], j, 0)),
-            pl.BlockSpec((1, 1), lambda i, ti, j, s: (s[i, ti], j)),
-            pl.BlockSpec((1, 1, dc), lambda i, ti, j, s: (s[i, ti], j, 0)),
-            pl.BlockSpec((1, 1), lambda i, ti, j, s: (s[i, ti], j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, dc), lambda i, ti, j, s: (s[i, ti], j, 0)),
-            pl.BlockSpec((1, 1), lambda i, ti, j, s: (s[i, ti], j)),
-            pl.BlockSpec((1, 1, dc), lambda i, ti, j, s: (s[i, ti], j, 0)),
-            pl.BlockSpec((1, 1), lambda i, ti, j, s: (s[i, ti], j)),
-        ],
-    )
-    return pl.pallas_call(
+    sq = pl.Squeezed()
+    row = pl.BlockSpec((sq, h, dc), lambda i, ti, s: (s[i, ti], 0, 0))
+    new = pl.BlockSpec((sq, sq, h, hd), lambda i, ti, s: (i, ti, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    kc, vc = pl.pallas_call(
         functools.partial(_paged_append_rows_kernel, fmt=fmt, packed=packed),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_codes.shape, k_codes.dtype),
-            jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
-            jax.ShapeDtypeStruct(v_codes.shape, v_codes.dtype),
-            jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, t),
+            in_specs=[new, new, hbm, hbm], out_specs=[row, row]),
+        out_shape=[jax.ShapeDtypeStruct(k_codes.shape, k_codes.dtype),
+                   jax.ShapeDtypeStruct(v_codes.shape, v_codes.dtype)],
         # operand indices include the scalar-prefetch arg (index 0)
-        input_output_aliases={3: 0, 4: 1, 5: 2, 6: 3},
+        input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
-    )(dst, k_new, v_new, k_codes, k_scale, v_codes, v_scale)
+    )(dst, kx, vx, k_codes, v_codes)
+    flat = dst.reshape(b * t)
+    return (kc, k_scale.at[flat].set(ks.reshape(b * t, h)), vc,
+            v_scale.at[flat].set(vs.reshape(b * t, h)))
 
 
 def paged_kv_append_rows_ref(k_codes, k_scale, v_codes, v_scale, k_new,
@@ -197,36 +183,14 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, kc_ref, ks_ref, vc_ref,
                        fmt, packed, ps, npg):
     del tbl_ref  # consumed by the index maps (page DMA addressing)
     bi = pl.program_id(0)
-    pi = pl.program_id(2)
-
-    @pl.when(pi == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # decode-on-read: one physical page's posit codes -> f32 in VMEM
-    kc = kc_ref[:, 0]                                          # (ps, Dc)
-    vc = vc_ref[:, 0]
-    k = decode_tile(unpack_nibbles(kc) if packed else kc,
-                    fmt, jnp.float32) * ks_ref[:, 0][:, None]  # (ps, hd)
-    v = decode_tile(unpack_nibbles(vc) if packed else vc,
-                    fmt, jnp.float32) * vs_ref[:, 0][:, None]
-    q = q_ref[0, 0].astype(jnp.float32)                        # (grp, hd)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)    # (grp, ps)
-    kpos = pi * ps + jnp.arange(ps)
-    s = jnp.where((kpos < len_ref[bi])[None, :], s, NEG_INF)
-    m_new = jnp.maximum(m_ref[...], s.max(-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_ref[...] - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    pi = pl.program_id(1)
+    flash_block(q_ref, kc_ref, ks_ref, vc_ref, vs_ref, m_ref, l_ref,
+                 acc_ref, pi == 0, len_ref[bi] - pi * ps, fmt=fmt,
+                 packed=packed)
 
     @pl.when(pi == npg - 1)
     def _finish():
-        o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        flash_finish(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "page_size", "packed",
@@ -241,8 +205,10 @@ def paged_decode_attention(q, k_codes, k_scale, v_codes, v_scale,
     page_table: (B, Pmax) i32 (entries must be valid physical pages —
     unallocated logical pages point at the trash page and are masked by
     ``seq_lens``); seq_lens: (B,) i32.  The grid's innermost dimension
-    walks the Pmax page-table entries of each (slot, kv-head) row with
-    (m, l, acc) carried in VMEM scratch.  Returns (B, 1, nh, hd)."""
+    walks the Pmax page-table entries of each slot; each step DMAs one
+    whole (page_size, nkv, Dc) page (all KV heads) with (m, l, acc)
+    carried in VMEM scratch.  On the TPU ``page_size`` must divide by 8.
+    Returns (B, 1, nh, hd)."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     r, nkv, dc = k_codes.shape
@@ -255,26 +221,17 @@ def paged_decode_attention(q, k_codes, k_scale, v_codes, v_scale,
     qg = (q.reshape(b, nkv, grp, hd) * (hd ** -0.5)).astype(jnp.float32)
     ps = page_size
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nkv, npg),
-        in_specs=[
-            pl.BlockSpec((1, 1, grp, hd), lambda i, j, p, t, ln: (i, j, 0, 0)),
-            pl.BlockSpec((ps, 1, dc), lambda i, j, p, t, ln: (t[i, p], j, 0)),
-            pl.BlockSpec((ps, 1), lambda i, j, p, t, ln: (t[i, p], j)),
-            pl.BlockSpec((ps, 1, dc), lambda i, j, p, t, ln: (t[i, p], j, 0)),
-            pl.BlockSpec((ps, 1), lambda i, j, p, t, ln: (t[i, p], j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, grp, hd),
-                               lambda i, j, p, t, ln: (i, j, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((grp, 1), jnp.float32),
-                        pltpu.VMEM((grp, 1), jnp.float32),
-                        pltpu.VMEM((grp, hd), jnp.float32)],
-    )
+    sq = pl.Squeezed()
+    codes = pl.BlockSpec((ps, nkv, dc), lambda i, p, t, ln: (t[i, p], 0, 0))
+    scales = pl.BlockSpec((ps, nkv), lambda i, p, t, ln: (t[i, p], 0))
+    heads = pl.BlockSpec((sq, nkv, grp, hd), lambda i, p, t, ln: (i, 0, 0, 0))
     out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, fmt=fmt, packed=packed,
                           ps=ps, npg=npg),
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, npg),
+            in_specs=[heads, codes, scales, codes, scales],
+            out_specs=heads, scratch_shapes=flash_scratch(nkv, grp, hd)),
         out_shape=jax.ShapeDtypeStruct((b, nkv, grp, hd), jnp.float32),
         interpret=interpret,
     )(tbl, lens, qg, k_codes, k_scale, v_codes, v_scale)

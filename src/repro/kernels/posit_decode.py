@@ -20,40 +20,53 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core.formats import PositFormat
-from ..core.posit import mask_u32, negate_code_u32, shl_u32, shr_u32
 
-U32 = jnp.uint32
+# Mosaic has no unsigned min/max/shift, so the codec keeps every field in
+# signed int32 (a posit of <= 16 bits never needs the sign bit).
+I32 = jnp.int32
+
+
+def mask_i32(b):
+    """(1 << b) - 1 as int32 for 0 <= b <= 30; ``b`` may be traced."""
+    return (jnp.int32(1) << b) - 1
+
+
+def check_kernel_format(fmt: PositFormat):
+    if fmt.bits > 16:
+        raise ValueError(f"kernel codec handles posits up to 16 bits, got "
+                         f"{fmt.name} ({fmt.bits} bits)")
 
 
 def decode_tile(codes, fmt: PositFormat, out_dtype=jnp.float32):
     """Decode a tile of posit codes to float. Pure jnp; Pallas-safe ops only
-    (compares, shifts, adds — no clz, no gather). Bit-exact for n<=16."""
+    (signed int32 compares, shifts, adds — no clz, no gather, no unsigned
+    arithmetic, which Mosaic cannot lower). Bit-exact for n<=16."""
+    check_kernel_format(fmt)
     n, es = fmt.bits, fmt.es
-    u = codes.astype(U32) & mask_u32(n)
+    u = codes.astype(I32) & mask_i32(n)
     is_zero = u == 0
-    is_nar = u == (U32(1) << U32(n - 1))
-    s = shr_u32(u, n - 1) & U32(1)
-    mag = jnp.where(s == 1, negate_code_u32(u, n), u)
-    body = mag & mask_u32(n - 1)
-    lead = shr_u32(body, n - 2) & U32(1)
-    t_val = jnp.where(lead == 1, body, (~body) & mask_u32(n - 1))
+    is_nar = u == (1 << (n - 1))
+    s = (u >> (n - 1)) & 1
+    mag = jnp.where(s == 1, (-u) & mask_i32(n), u)
+    body = mag & mask_i32(n - 1)
+    lead = (body >> (n - 2)) & 1
+    t_val = jnp.where(lead == 1, body, (~body) & mask_i32(n - 1))
     # --- Algorithm 1: parallel threshold comparisons (unrolled, VPU) ---
     r = jnp.zeros_like(u)
     for i in range(n - 1):
-        thr = U32((1 << (n - 1)) - (1 << i))  # 2^{n-1}-1-(2^i-1)
-        r = r + (t_val >= thr).astype(U32)
-    k = jnp.where(lead == 1, r.astype(jnp.int32) - 1, -r.astype(jnp.int32))
-    rem_i = jnp.maximum(jnp.int32(n - 1) - r.astype(jnp.int32) - 1, 0)
-    rem = rem_i.astype(U32)
-    rest = body & mask_u32(rem)
-    e_have = jnp.minimum(rem, U32(es))
-    e_field = shl_u32(shr_u32(rest, rem - e_have), U32(es) - e_have)
-    f_len = jnp.maximum(rem_i - es, 0).astype(U32)
-    f_field = rest & mask_u32(f_len)
-    t = (k << es) + e_field.astype(jnp.int32) + fmt.bias
+        thr = (1 << (n - 1)) - (1 << i)  # 2^{n-1}-1-(2^i-1)
+        r = r + (t_val >= thr).astype(I32)
+    k = jnp.where(lead == 1, r - 1, -r)
+    rem = jnp.maximum(n - 2 - r, 0)
+    rest = body & mask_i32(rem)
+    e_have = jnp.minimum(rem, es)
+    e_field = (rest >> (rem - e_have)) << (es - e_have)
+    f_len = jnp.maximum(rem - es, 0)
+    f_field = rest & mask_i32(f_len)
+    t = (k << es) + e_field + fmt.bias
     # --- IEEE-754 assembly (f_len <= 13 <= 23: exact) ---
-    man = shl_u32(f_field, U32(23) - f_len)
-    bits = shl_u32(s, 31) | shl_u32((t + 127).astype(U32), 23) | man
+    man = f_field << (23 - f_len)
+    bits = (s << 31) | ((t + 127) << 23) | man
     val = jax.lax.bitcast_convert_type(bits, jnp.float32)
     val = jnp.where(is_zero, 0.0, val)
     val = jnp.where(is_nar, jnp.nan, val)

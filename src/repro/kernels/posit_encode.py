@@ -17,59 +17,57 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core.formats import PositFormat
-from ..core.posit import mask_u32, negate_code_u32, shl_u32, shr_u32
-
-U32 = jnp.uint32
+from .posit_decode import I32, check_kernel_format, mask_i32
 
 
 def encode_tile(x, fmt: PositFormat):
-    """Encode a float32 tile to posit codes. Pallas-safe; bit-exact RNE for
-    normal floats (subnormals flushed — see module docstring)."""
+    """Encode a float32 tile to posit codes. Pallas-safe (signed int32 only,
+    narrowed to the storage dtype at the end); bit-exact RNE for normal
+    floats (subnormals flushed — see module docstring)."""
+    check_kernel_format(fmt)
     n, es = fmt.bits, fmt.es
-    xf = x.astype(jnp.float32)
-    bits = jax.lax.bitcast_convert_type(xf, jnp.int32).astype(U32)
-    s = shr_u32(bits, 31)
-    exp_raw = (shr_u32(bits, 23) & mask_u32(8)).astype(jnp.int32)
-    frac = bits & mask_u32(23)
-    is_zero = (bits & mask_u32(31)) == 0
-    is_zero = is_zero | (exp_raw == 0)  # flush subnormals
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), I32)
+    s = (bits >> 31) & 1
+    exp_raw = (bits >> 23) & 0xFF
+    frac = bits & mask_i32(23)
+    is_zero = ((bits & 0x7FFFFFFF) == 0) | (exp_raw == 0)  # flush subnormals
     is_nar = exp_raw == 255
     t = exp_raw - 127 - fmt.bias
     fw = 23
     # --- regime/exponent split ---
     k = t >> es
-    e_field = (t - (k << es)).astype(U32)
+    e_field = t - (k << es)
     sat_hi = k >= n - 2
     sat_lo = k <= -(n - 1)
     k_c = jnp.clip(k, -(n - 2), n - 3)
     pos = k_c >= 0
     w0 = jnp.where(pos, k_c + 2, 1 - k_c)
-    reg = jnp.where(pos, shl_u32(mask_u32((k_c + 1).astype(U32)), 1), U32(1))
-    avail = jnp.int32(n - 1) - w0
+    reg = jnp.where(pos, mask_i32(k_c + 1) << 1, 1)
+    avail = (n - 1) - w0
     ef_shift = avail + 1 - es
     # --- case ef_shift >= 0 ---
-    efp = jnp.maximum(ef_shift, 0).astype(U32)
-    take = jnp.minimum(efp, U32(fw))
-    fbits = shl_u32(shr_u32(frac, U32(fw) - take), efp - take)
-    st_a = (frac & mask_u32(U32(fw) - take)) != 0
-    efg_a = shl_u32(e_field, efp) | fbits
+    efp = jnp.maximum(ef_shift, 0)
+    take = jnp.minimum(efp, fw)
+    fbits = (frac >> (fw - take)) << (efp - take)
+    st_a = ((frac & mask_i32(fw - take)) != 0).astype(I32)
+    efg_a = (e_field << efp) | fbits
     # --- case ef_shift < 0 ---
-    cut = jnp.maximum(-ef_shift, 0).astype(U32)
-    efg_b = shr_u32(e_field, cut)
-    st_b = ((e_field & mask_u32(cut)) != 0) | (frac != 0)
+    cut = jnp.maximum(-ef_shift, 0)
+    efg_b = e_field >> cut
+    st_b = (((e_field & mask_i32(cut)) != 0) | (frac != 0)).astype(I32)
     neg_case = ef_shift < 0
     efg = jnp.where(neg_case, efg_b, efg_a)
     st = jnp.where(neg_case, st_b, st_a)
-    guard = efg & U32(1)
-    kept = shr_u32(efg, 1)
-    body = shl_u32(reg, avail.astype(U32)) | kept
-    body = body + (guard & (st.astype(U32) | (body & U32(1))))
-    body = jnp.where(sat_hi, mask_u32(n - 1), body)
-    body = jnp.where(sat_lo, U32(1), body)
-    body = jnp.clip(body, U32(1), mask_u32(n - 1))
-    code = jnp.where(s == 1, negate_code_u32(body, n), body)
-    code = jnp.where(is_zero, U32(0), code)
-    code = jnp.where(is_nar, U32(1) << U32(n - 1), code)
+    guard = efg & 1
+    kept = efg >> 1
+    body = (reg << avail) | kept
+    body = body + (guard & (st | (body & 1)))
+    body = jnp.where(sat_hi, mask_i32(n - 1), body)
+    body = jnp.where(sat_lo, 1, body)
+    body = jnp.clip(body, 1, mask_i32(n - 1))
+    code = jnp.where(s == 1, (-body) & mask_i32(n), body)
+    code = jnp.where(is_zero, 0, code)
+    code = jnp.where(is_nar, 1 << (n - 1), code)
     return code.astype(fmt.storage_dtype)
 
 

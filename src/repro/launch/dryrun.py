@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 # ---------------------------------------------------------------------------
 # Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell against
 # the production mesh, prove it fits, and extract the roofline terms.
@@ -8,13 +5,15 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 #   PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-8b \
 #       --shape train_4k --mesh pod1 --out benchmarks/results/dryrun
 #
-# The XLA_FLAGS line above MUST precede any jax import: jax locks the device
-# count on first init.  512 placeholder host devices back both the single-pod
-# (16,16) and multi-pod (2,16,16) meshes.
+# main() pins the process to 512 placeholder host devices before JAX
+# initialises a backend (jax locks the device count on first init); they
+# back both the single-pod (16,16) and multi-pod (2,16,16) meshes.  The
+# dry-run therefore never takes an accelerator, even on a machine with one.
 # ---------------------------------------------------------------------------
 import argparse
 import dataclasses
 import json
+import os
 import re
 import time
 from typing import Any, Dict, Optional
@@ -327,7 +326,21 @@ def lower_cell(arch: str, shape: str, multi_pod: bool,
     return report
 
 
+HOST_DEVICES = 512
+
+
+def _use_host_devices():
+    """Select the CPU backend with ``HOST_DEVICES`` placeholder devices.
+    Must run before anything touches a JAX backend."""
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS", ""),
+        f"--xla_force_host_platform_device_count={HOST_DEVICES}"]))
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+
+
 def main():
+    _use_host_devices()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True, choices=list(SHAPES))

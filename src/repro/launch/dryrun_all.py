@@ -31,7 +31,9 @@ def run_cell(arch, shape, mesh, out, extra=()):
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
          "--shape", shape, "--mesh", mesh, "--out", out, *extra],
         capture_output=True, text=True,
-        env={**os.environ, "PYTHONPATH": "src"})
+        # the children compile on placeholder CPU devices and must never
+        # take an accelerator this machine may hold
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"})
     dt = time.time() - t0
     if proc.returncode != 0:
         tail = proc.stderr.strip().splitlines()[-12:]

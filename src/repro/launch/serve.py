@@ -45,11 +45,18 @@ precision-fallback ladder.  ``--deadline-s`` / ``--watchdog-s`` bound
 per-request and scheduler-stall time in async mode, and ``--health``
 prints the orchestrator's health snapshot (thread liveness, in-flight
 depth, fault/guard counters) before exit.
+
+The exit code is 0 only when every request reached its end without an
+error (and, in async mode, the orchestrator stayed healthy and every
+submitted stream finished).  ``--expect-errors`` waives the per-request
+part for runs that arm faults on purpose.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import sys
+from collections import Counter
 from time import perf_counter
 
 import jax
@@ -60,9 +67,11 @@ from ..core.transprecision import PRESETS
 from ..models import lm
 from ..obs import format_breakdown, stage_breakdown
 from ..serve.engine import Request, ServeConfig, ServingEngine
+from .compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-edge")
     ap.add_argument("--full", action="store_true")
@@ -148,6 +157,10 @@ def main():
                          "liveness, threads, in-flight depth, engine "
                          "occupancy, faults/guard counters) before exit; "
                          "sync mode prints the counter subset only")
+    ap.add_argument("--expect-errors", action="store_true",
+                    help="exit 0 even if requests end in a terminal error "
+                         "(for runs whose fault plan causes them on "
+                         "purpose); an unhealthy orchestrator still fails")
     args = ap.parse_args()
 
     if args.speculative and args.temperature > 0:
@@ -215,6 +228,23 @@ def main():
              if k.startswith(("faults.", "guard."))
              or k in ("stage.retries", "stage.retry_exhausted")}))
     _write_obs(engine, wall, args)
+    errs = _count_errors(r.error for r in reqs)
+    return _exit_code(errs, args)
+
+
+def _count_errors(errors):
+    return dict(Counter(e for e in errors if e is not None))
+
+
+def _exit_code(errs, args, failures=()):
+    """1 when the run failed: a failure of the serving machinery itself
+    (``failures``), or a request error the caller did not expect."""
+    failures = list(failures)
+    if errs and not args.expect_errors:
+        failures.append(f"requests ended in terminal errors: {errs}")
+    for f in failures:
+        print(f"FAILED: {f}", file=sys.stderr)
+    return 1 if failures else 0
 
 
 def _write_obs(engine, wall_s, args):
@@ -254,16 +284,18 @@ def _serve_async(engine, cfg, rng, args):
     # __exit__ would re-raise its exception — we want to keep going and
     # report the health snapshot instead
     orch = Orchestrator(engine, ocfg)
-    submitted = []
+    submitted, failures = [], []
     try:
         for s in sreqs:
             try:
                 ok = orch.submit(s)
             except RuntimeError as e:   # orchestrator went unhealthy
                 print(f"submit refused: {e}")
+                failures.append(f"submit refused: {e}")
                 break
             if not ok:
                 print("request timed out in admission; dropping")
+                failures.append("a request timed out in admission")
                 continue
             submitted.append(s)
             if args.rate > 0:
@@ -271,8 +303,11 @@ def _serve_async(engine, cfg, rng, args):
         # containment guarantees every submitted request reaches a
         # terminal state, so these waits cannot hang; the timeout is a
         # belt-and-suspenders bound for the launcher itself
-        for s in submitted:
-            s.wait(timeout=300.0)
+        unfinished = sum(not s.wait(timeout=300.0) for s in submitted)
+        if unfinished:
+            failures.append(f"{unfinished} streams never finished")
+        if not orch.healthy:
+            failures.append(f"orchestrator unhealthy: {orch.health()['error']}")
         if args.health:
             print("health:", json.dumps(orch.health()))
     finally:
@@ -280,10 +315,8 @@ def _serve_async(engine, cfg, rng, args):
             orch.close()
         except RuntimeError as e:       # leaked-thread detection
             print(f"close: {e}")
-    errs = {}
-    for s in submitted:
-        if s.error is not None:
-            errs[s.error] = errs.get(s.error, 0) + 1
+            failures.append(f"close: {e}")
+    errs = _count_errors(s.error for s in submitted)
     if errs:
         print("terminal errors:", errs)
     wall = perf_counter() - t0
@@ -308,7 +341,8 @@ def _serve_async(engine, cfg, rng, args):
     if args.request_log:
         print(f"request log -> {args.request_log}")
     _write_obs(engine, wall, args)
+    return _exit_code(errs, args, failures)
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

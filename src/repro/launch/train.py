@@ -13,9 +13,11 @@ from ..core.transprecision import PRESETS
 from ..configs import get_config
 from ..optim import AdamWConfig
 from ..train import Trainer, TrainerConfig
+from .compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-edge")
     ap.add_argument("--full", action="store_true")

@@ -23,29 +23,12 @@ automatic), so it composes with the pjit-sharded rest of the decode step.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models import serve_model
 from ..models.attention import NEG_INF
-
-
-def _shard_map(fn, mesh: Mesh, in_specs, out_specs, axis: str):
-    """shard_map across JAX versions: ``jax.shard_map`` (new) with manual
-    ``axis`` only, or ``jax.experimental.shard_map`` (<=0.4.x) with the
-    other mesh axes auto."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False,
-                             axis_names={axis})
-    from jax.experimental.shard_map import shard_map as sm
-    auto = frozenset(set(mesh.axis_names) - {axis})
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False, auto=auto)
 
 
 def _local_lse(q, k, v, start, cache_len):
@@ -134,12 +117,12 @@ def distributed_decode_attention(mesh: Mesh, axis: str = "model",
                 return (num / jnp.maximum(den, 1e-30)[..., None]).astype(
                     q.dtype)
 
-            out = _shard_map(
-                shard_fn, mesh,
+            out = jax.shard_map(
+                shard_fn, mesh=mesh,
                 in_specs=(P(), P(axis, None, None), P(axis, None),
                           P(axis, None, None), P(axis, None), P(), P()),
-                out_specs=P(), axis=axis)(qg, k_codes, k_scale, v_codes,
-                                          v_scale, tbl, lens)
+                out_specs=P(), axis_names={axis}, check_vma=False)(
+                    qg, k_codes, k_scale, v_codes, v_scale, tbl, lens)
             return out.reshape(b, 1, nh, hd)
 
         attn_paged.paged_kv = True
@@ -170,14 +153,14 @@ def distributed_decode_attention(mesh: Mesh, axis: str = "model",
                 return (num / jnp.maximum(den, 1e-30)[..., None]).astype(
                     q.dtype)
 
-            out = _shard_map(
-                shard_fn, mesh,
+            out = jax.shard_map(
+                shard_fn, mesh=mesh,
                 in_specs=(P(), P(None, axis, None, None),
                           P(None, axis, None),
                           P(None, axis, None, None),
                           P(None, axis, None), P()),
-                out_specs=P(), axis=axis)(qg, k_codes, k_scale, v_codes,
-                                          v_scale, cache_len)
+                out_specs=P(), axis_names={axis}, check_vma=False)(
+                    qg, k_codes, k_scale, v_codes, v_scale, cache_len)
             return out.reshape(b, 1, nh, hd)
 
         attn_packed.packed_kv = True
@@ -200,11 +183,12 @@ def distributed_decode_attention(mesh: Mesh, axis: str = "model",
             den = jax.lax.psum(l * corr, axis)
             return (num / jnp.maximum(den, 1e-30)[..., None]).astype(q.dtype)
 
-        out = _shard_map(
-            shard_fn, mesh,
+        out = jax.shard_map(
+            shard_fn, mesh=mesh,
             in_specs=(P(), P(None, axis, None, None),
                       P(None, axis, None, None), P()),
-            out_specs=P(), axis=axis)(qg, k_cache, v_cache, cache_len)
+            out_specs=P(), axis_names={axis}, check_vma=False)(
+                qg, k_cache, v_cache, cache_len)
         return out.reshape(b, 1, nh, hd)
 
     return attn

@@ -245,9 +245,9 @@ class TransprecisionEngine:
         else:
             self._prefill_policy = self.policy
         # donation keeps per-stage state updates from copying the whole
-        # batch cache (ignored with a warning on CPU, so default off there)
-        self._donate = ((jax.default_backend() != "cpu")
-                        if donate is None else donate)
+        # batch cache; on by default on every backend, so the CPU tests
+        # exercise the same donated buffers the chip does
+        self._donate = True if donate is None else donate
         self._prefill_jits: Dict[Any, Any] = {}
         self._insert_jits: Dict[Any, Any] = {}
         self._verify_jits: Dict[int, Any] = {}

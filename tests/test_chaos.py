@@ -449,3 +449,32 @@ def test_fallback_ladder_shapes():
     (retry_rung,) = fallback_ladder(BF16)    # full precision: one retry
     assert retry_rung.attn_weights is None
     assert "guard_retry" in retry_rung.name
+
+
+@pytest.mark.parametrize("case,extra,want_rc", [
+    ("deadline", [], 1),
+    ("deadline", ["--expect-errors"], 0),
+    ("crash", ["--expect-errors"], 1),
+])
+def test_launcher_exit_code(monkeypatch, tmp_path, capsys, case, extra,
+                            want_rc):
+    """``launch/serve.py --async`` exits 1 when a request ends in an error
+    it was not told to expect, and when the orchestrator goes unhealthy
+    even if it was (a persistent prefill fault crashes the scheduler)."""
+    from repro.launch import serve
+    if case == "deadline":                # healthy; every request expires
+        args = ["--deadline-s", "0.001"]
+    else:
+        plan = tmp_path / "plan.json"
+        plan.write_text(json.dumps([{"kind": "stage_error",
+                                     "stage": "prefill", "at": 0,
+                                     "transient": False}]))
+        args = ["--fault-plan", str(plan)]
+    # the launcher's persistent compile cache stays off under the tests
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr("sys.argv", [
+        "serve", "--async", "--requests", "3", "--max-new", "3",
+        "--batch", "2", "--max-len", str(MAX_LEN), *args, *extra])
+    assert serve.main() == want_rc
+    err = capsys.readouterr().err
+    assert ("FAILED" in err) == bool(want_rc)

@@ -168,3 +168,21 @@ def test_elastic_data_resharding_is_lossless():
         parts = [pipe.host_batch(5, h, n_hosts)["tokens"]
                  for h in range(n_hosts)]
         np.testing.assert_array_equal(np.concatenate(parts), full)
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """Entry points honour ``JAX_COMPILATION_CACHE_DIR`` and set no other
+    directory; unset, the cache goes to the fixed ``<repo>/.jax_cache``."""
+    from repro.launch import compile_cache as cc
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cc.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == was
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert cc.enable_compile_cache() == str(cc.REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(cc.REPO_CACHE_DIR)
+    finally:                              # the tests never keep it on
+        jax.config.update("jax_compilation_cache_dir", was)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert str(cc.REPO_CACHE_DIR) == os.path.join(repo, ".jax_cache")
