@@ -1,0 +1,4 @@
+"""``generate_roofline`` in a cell judged on output tokens per second."""
+from metrics_common import load_sibling
+
+read = load_sibling("generate_roofline").read
