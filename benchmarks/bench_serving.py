@@ -22,10 +22,10 @@ Rate accounting: the measurement window runs from the FIRST submit to
 the LAST finish (both ``perf_counter`` stamps recorded by the
 orchestrator), so achieved_rps can never exceed the offered rate beyond
 the N/(N-1) edge correction — asserted per load point.  Each load point
-also carries a per-stage wall-clock breakdown (dispatch vs device-sync
-per engine stage, orchestrator overhead) from the span tracer
-(:mod:`repro.obs`); set ``REPRO_TRACE=1`` to additionally write the full
-Chrome trace to ``results/BENCH_serving.trace.json``.
+also carries a per-stage wall-clock breakdown (dispatch per engine
+stage, the engine's tick phases, orchestrator overhead) from the span
+tracer (:mod:`repro.obs`); set ``REPRO_TRACE=1`` to run the sweep under
+a profiler session, whose trace lands in ``results/BENCH_serving.trace/``.
 
 Each load point also reports modeled **energy** (:mod:`repro.obs.energy`:
 TALU pJ/MAC x HLO FLOPs + DRAM pJ/byte x HBM bytes, times the per-stage
@@ -172,13 +172,23 @@ def _run_load(eng, prompts, rate_rps, rng, acct=None, request_log=None):
 
 
 def run():
+    """The sweep; with ``REPRO_TRACE`` set, under a profiler session whose
+    trace lands in ``results/BENCH_serving.trace/``."""
+    if not os.environ.get("REPRO_TRACE"):
+        return _sweep()
+    path = os.path.join(RESULTS_DIR, "BENCH_serving.trace")
+    with jax.profiler.trace(path):
+        out = _sweep()
+    out["trace_dir"] = os.path.basename(path)
+    return out
+
+
+def _sweep():
     cfg = get_config("paper-edge", smoke=True)
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     scfg = ServeConfig(max_batch=MAX_BATCH, max_len=MAX_LEN,
                        kv_format=KV_FORMAT)
-    # big ring so the whole sweep survives for the optional trace export
-    eng = ServingEngine(cfg, params, scfg,
-                        tracer=Tracer(capacity=1 << 18, enabled=True))
+    eng = ServingEngine(cfg, params, scfg, tracer=Tracer(enabled=True))
     prompts = _prompts(cfg)
     acct = EnergyAccountant(eng)
     os.makedirs(RESULTS_DIR, exist_ok=True)
@@ -204,11 +214,6 @@ def run():
         out["loads"].append(m)
     # cumulative table (per-stage pJ, precision mix) over the whole run
     out["energy_breakdown"] = acct.breakdown()
-    if os.environ.get("REPRO_TRACE"):
-        os.makedirs(RESULTS_DIR, exist_ok=True)
-        path = os.path.join(RESULTS_DIR, "BENCH_serving.trace.json")
-        eng.tracer.write_chrome_trace(path)
-        out["trace_file"] = os.path.basename(path)
     return out
 
 

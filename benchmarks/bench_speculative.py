@@ -20,12 +20,12 @@ Acceptance target (ISSUE 3): identical streams and < 1.0 target
 steps/token at gamma >= 2.
 
 Every cell (and each layout's baseline) carries a ``stage_breakdown``
-from the span tracer (:mod:`repro.obs`): per-stage dispatch vs
-device-sync seconds (draft stages prefixed ``draft.``), host overhead,
-and the fraction of wall attributed — the data behind ROADMAP direction
-1's "why is speculative wall-clock slower" question.  Set
-``REPRO_TRACE=1`` to also write the sweep's Chrome trace to
-``results/BENCH_speculative.trace.json``.
+from the span tracer (:mod:`repro.obs`): per-stage dispatch seconds
+(draft stages prefixed ``draft.``), host overhead, and the fraction of
+wall attributed — the data behind ROADMAP direction 1's "why is
+speculative wall-clock slower" question.  Set ``REPRO_TRACE=1`` to run
+the sweep under a profiler session, whose trace (device time per stage
+among it) lands in ``results/BENCH_speculative.trace/``.
 
 Writes the machine-readable artifact ``benchmarks/results/
 BENCH_speculative.json``.
@@ -79,11 +79,23 @@ def _serve(engine_f, cfg, tracer):
 
 
 def run():
+    """The sweep; with ``REPRO_TRACE`` set, under a profiler session whose
+    trace lands in ``results/BENCH_speculative.trace/``."""
+    if not os.environ.get("REPRO_TRACE"):
+        return _sweep()
+    path = os.path.join(RESULTS_DIR, "BENCH_speculative.trace")
+    with jax.profiler.trace(path):
+        out = _sweep()
+    out["trace_dir"] = os.path.basename(path)
+    return out
+
+
+def _sweep():
     cfg = get_config("paper-edge", smoke=True)
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     # one tracer across the whole sweep: per-cell deltas via since=
-    # snapshots, one Chrome trace covering every cell at the end
-    tracer = Tracer(capacity=1 << 18, enabled=True)
+    # snapshots
+    tracer = Tracer(enabled=True)
     out = {"shape": {"max_batch": MAX_BATCH, "max_len": MAX_LEN,
                      "page_size": PAGE_SIZE, "max_new": MAX_NEW,
                      "requests": N_REQ, "kv_format": KV_FORMAT},
@@ -142,11 +154,6 @@ def run():
         c["target_steps_per_token"] for c in cells)
     out["draft_energy_below_target"] = all(
         c["energy"]["draft_below_target"] for c in cells)
-    if os.environ.get("REPRO_TRACE"):
-        os.makedirs(RESULTS_DIR, exist_ok=True)
-        path = os.path.join(RESULTS_DIR, "BENCH_speculative.trace.json")
-        tracer.write_chrome_trace(path)
-        out["trace_file"] = os.path.basename(path)
     return out
 
 

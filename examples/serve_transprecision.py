@@ -165,14 +165,15 @@ def main():
 
     # --- observability (PR 5): spans, metrics, stage attribution -------
     # Every engine carries a span tracer and a metrics registry
-    # (repro.obs).  With the tracer enabled, each engine stage records a
-    # host-dispatch span (Python + jit dispatch) and a device span (the
-    # block_until_ready wait), so the wall clock decomposes into
-    # per-stage dispatch vs device time — the tool for ROADMAP direction
-    # 1's "where does the speculative wall clock go" question.  Disabled
-    # (the default), the spans cost ~nothing and the engine never
-    # synchronizes.  The same registry backs engine.stats / orch.stats,
-    # with latency histograms (p50/p95/p99) per stage for free.
+    # (repro.obs).  With the tracer enabled, each engine stage's
+    # dispatch (Python + jit dispatch) and each phase of the decode tick
+    # (pages, logits copy, sampling, emit) is a span with exact self
+    # times, so the host's wall clock decomposes per stage and phase.
+    # Nothing waits for the device: device time per stage comes from a
+    # profiler trace, where the same spans land on the device's clock.
+    # Disabled (the default), the spans cost ~nothing.  The same
+    # registry backs engine.stats / orch.stats, with latency histograms
+    # (p50/p95/p99) per stage for free.
     from time import perf_counter
 
     from repro.obs import Tracer, format_breakdown, stage_breakdown
@@ -191,9 +192,10 @@ def main():
     gen = engine.metrics.histogram("stage.generate.dispatch_s")
     print(f"  generate dispatch p50/p99: {gen.percentile(50) * 1e3:.1f}/"
           f"{gen.percentile(99) * 1e3:.1f} ms over {gen.count} calls")
-    # engine.tracer.write_chrome_trace("serve.trace.json") -> load the
-    # file in chrome://tracing or https://ui.perfetto.dev; the CLI
-    # equivalent is `python -m repro.launch.serve --trace-out ...`
+    # serve under `with jax.profiler.trace("serve_trace"):` to get the
+    # spans and the device's ops on one clock (jax.profiler.ProfileData,
+    # TensorBoard or Perfetto); the CLI equivalent is
+    # `python -m repro.launch.serve --trace-out DIR`
 
     # --- energy & SLO observability (PR 8) -----------------------------
     # EnergyAccountant prices each jitted stage from its *compiled* HLO:

@@ -17,11 +17,13 @@ TTFT and inter-token latency percentiles.  ``--overcommit`` (paged
 layout) admits on current page demand instead of the worst case and
 evicts/requeues the newest sequence if the pool runs dry.
 
-Observability (``repro.obs``): ``--trace-out run.trace.json`` enables
-the span tracer and writes a Chrome-trace file (open in
-``chrome://tracing`` or https://ui.perfetto.dev) covering engine stage
-dispatch/device-sync, orchestrator loop segments and the detokenizer
-thread; a per-stage wall-clock breakdown table is printed at exit.
+Observability (``repro.obs``): ``--trace-out DIR`` runs the serving
+under a profiler session and writes its trace (``.xplane.pb`` under
+``DIR/plugins/profile/``; ``jax.profiler.ProfileData`` loads it): the
+program's spans (engine stage dispatch, the decode tick's phases and
+admission, orchestrator loop segments, the detokenizer thread, Python
+collections) and, on a chip, the device's operations, on one clock; a
+per-stage wall-clock breakdown table is printed at exit.
 ``--metrics-json metrics.json`` dumps the full metrics-registry
 snapshot (counters, gauges, latency histograms with p50/p95/p99).
 
@@ -54,6 +56,7 @@ part for runs that arm faults on purpose.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 from collections import Counter
@@ -117,10 +120,13 @@ def main():
     ap.add_argument("--draft-kv-format", default="posit8",
                     choices=["f32", "bf16", "posit16", "posit8", "posit4"],
                     help="speculative: draft-pass KV storage format")
-    ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="enable span tracing and write a Chrome-trace "
-                         "JSON (chrome://tracing / Perfetto) on exit; "
-                         "also prints a per-stage wall-clock breakdown")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="write a profiler trace of the run to DIR "
+                         "(the program's spans and, on a chip, the "
+                         "device's ops, on one clock; load it with "
+                         "jax.profiler.ProfileData, TensorBoard or "
+                         "Perfetto) and print a per-stage wall-clock "
+                         "breakdown")
     ap.add_argument("--metrics-json", default=None, metavar="PATH",
                     help="write the metrics-registry snapshot (counters, "
                          "gauges, latency histograms) on exit")
@@ -194,8 +200,16 @@ def main():
     if args.trace_out:
         engine.tracer.enable()
     rng = np.random.default_rng(0)
-    if args.async_:
-        return _serve_async(engine, cfg, rng, args)
+    serve = _serve_async if args.async_ else _serve_sync
+    with (jax.profiler.trace(args.trace_out) if args.trace_out
+          else contextlib.nullcontext()):
+        code = serve(engine, cfg, rng, args)
+    if args.trace_out:
+        print(f"profiler trace -> {args.trace_out}")
+    return code
+
+
+def _serve_sync(engine, cfg, rng, args):
     reqs = [Request(uid=i,
                     prompt=rng.integers(0, cfg.vocab, rng.integers(4, 17)),
                     max_new=args.max_new)
@@ -251,8 +265,6 @@ def _write_obs(engine, wall_s, args):
     """Dump trace / metrics files and print the stage breakdown."""
     if args.trace_out:
         print(format_breakdown(stage_breakdown(engine.tracer, wall_s)))
-        engine.tracer.write_chrome_trace(args.trace_out)
-        print(f"chrome trace -> {args.trace_out}")
     if args.metrics_json:
         with open(args.metrics_json, "w") as f:
             json.dump(engine.metrics.snapshot(), f, indent=1)

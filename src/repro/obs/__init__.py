@@ -1,11 +1,14 @@
 """Serving observability: span tracing, metrics, time attribution.
 
-* :mod:`repro.obs.tracer` — low-overhead thread-aware span tracer with
-  Chrome-trace/Perfetto export (``Tracer``);
+* :mod:`repro.obs.tracer` — low-overhead thread-aware span tracer
+  (``Tracer``): spans are profiler ``TraceMe`` events whenever a
+  profiler session is active, so they share the device trace's clock,
+  and exact per-name self-time aggregates while enabled;
 * :mod:`repro.obs.metrics` — typed metrics registry (counters, gauges,
   log-bucketed latency histograms) + the ``StatsView`` legacy facade;
 * :mod:`repro.obs.report` — per-stage wall-clock attribution
-  (``stage_breakdown``) separating host-dispatch from device time;
+  (``stage_breakdown``) of the host's time to stage dispatch and host
+  buckets;
 * :mod:`repro.obs.energy` — modeled joules/token accounting
   (``EnergyAccountant``): loop-aware HLO cost analysis of each compiled
   engine stage priced with the paper's TALU per-MAC PDP row plus a

@@ -3,11 +3,12 @@
 Turns a :class:`~repro.obs.tracer.Tracer`'s exact self-time aggregates
 into the breakdown the benchmarks publish in ``BENCH_*.json``: for each
 engine stage (prefill / insert / generate / verify / rollback, plus the
-``draft.``-prefixed speculative draft stages) the **host-dispatch** time
-(Python + jit dispatch until the stage call returns) and the **device**
-time (the ``jax.block_until_ready`` wait that follows), plus the
-explicitly measured host buckets (sampling, orchestrator segments,
-allocator work) and the unattributed remainder.
+``draft.``-prefixed speculative draft stages; the ``stage.<name>``
+spans) the **host-dispatch** time (Python + jit dispatch until the stage
+call returns), plus the explicitly measured host buckets (the engine's
+tick phases, orchestrator segments, allocator work) and the
+unattributed remainder.  Device time per stage is not a host span: it
+is read from a profiler trace of the device's programs.
 
 Because the inputs are per-span *self* times (child spans subtracted,
 see ``Tracer.self_times``), the buckets are disjoint by construction on
@@ -62,8 +63,8 @@ def stage_breakdown(tracer, wall_s: float, *,
 
     Returns::
 
-        {"wall_s": ..., "stages": {stage: {"dispatch_s", "device_s",
-         "calls"}}, "host": {bucket: seconds}, "concurrent": {...},
+        {"wall_s": ..., "stages": {stage: {"dispatch_s", "calls"}},
+         "host": {bucket: seconds}, "concurrent": {...},
          "queue": {span: {"total_s", "count"}},
          "attributed_s": ..., "unattributed_s": ...,
          "attributed_frac": ...}
@@ -82,14 +83,10 @@ def stage_breakdown(tracer, wall_s: float, *,
     attributed = 0.0
     for name, rec in agg.items():
         if rec["cat"] == "engine":
-            stage, _, kind = name.rpartition(".")
-            s = stages.setdefault(stage, {"dispatch_s": 0.0,
-                                          "device_s": 0.0, "calls": 0})
-            if kind == "dispatch":
-                s["dispatch_s"] += rec["self_s"]
-                s["calls"] += rec["count"]
-            else:
-                s["device_s"] += rec["self_s"]
+            s = stages.setdefault(name.removeprefix("stage."),
+                                  {"dispatch_s": 0.0, "calls": 0})
+            s["dispatch_s"] += rec["self_s"]
+            s["calls"] += rec["count"]
             attributed += rec["self_s"]
         elif rec["cat"] in QUEUE_CATS:
             q = queue.setdefault(name, {"total_s": 0.0, "count": 0})
@@ -106,7 +103,6 @@ def stage_breakdown(tracer, wall_s: float, *,
     unattributed = max(wall_s - attributed, 0.0)
     return {"wall_s": wall_s,
             "stages": {k: {"dispatch_s": v["dispatch_s"],
-                           "device_s": v["device_s"],
                            "calls": int(v["calls"])}
                        for k, v in sorted(stages.items())},
             "host": dict(sorted(host.items())),
@@ -121,23 +117,22 @@ def stage_breakdown(tracer, wall_s: float, *,
 def format_breakdown(bd: Dict[str, Any]) -> str:
     """Human-readable table of a :func:`stage_breakdown` result."""
     wall = bd["wall_s"]
-    lines = [f"{'stage':<22s} {'dispatch':>10s} {'device':>10s} "
-             f"{'calls':>7s} {'% wall':>7s}"]
+    lines = [f"{'stage':<22s} {'dispatch':>10s} {'calls':>7s} "
+             f"{'% wall':>7s}"]
     for name, s in bd["stages"].items():
-        tot = s["dispatch_s"] + s["device_s"]
         lines.append(f"{name:<22s} {s['dispatch_s'] * 1e3:>8.1f}ms "
-                     f"{s['device_s'] * 1e3:>8.1f}ms {s['calls']:>7d} "
-                     f"{100 * tot / wall:>6.1f}%")
+                     f"{s['calls']:>7d} "
+                     f"{100 * s['dispatch_s'] / wall:>6.1f}%")
     for name, v in bd["host"].items():
-        lines.append(f"{name:<22s} {v * 1e3:>8.1f}ms {'':>10s} {'':>7s} "
+        lines.append(f"{name:<22s} {v * 1e3:>8.1f}ms {'':>7s} "
                      f"{100 * v / wall:>6.1f}%")
     for name, v in bd["concurrent"].items():
         lines.append(f"{name + ' (conc.)':<22s} {v * 1e3:>8.1f}ms")
     for name, q in bd.get("queue", {}).items():
         lines.append(f"{name + ' (queue)':<22s} {q['total_s'] * 1e3:>8.1f}ms"
-                     f" {'':>10s} {q['count']:>7d}")
+                     f" {q['count']:>7d}")
     lines.append(f"{'(unattributed)':<22s} "
-                 f"{bd['unattributed_s'] * 1e3:>8.1f}ms {'':>10s} {'':>7s} "
+                 f"{bd['unattributed_s'] * 1e3:>8.1f}ms {'':>7s} "
                  f"{100 * bd['unattributed_s'] / wall:>6.1f}%")
     lines.append(f"attributed {100 * bd['attributed_frac']:.1f}% of "
                  f"{wall * 1e3:.1f}ms wall")

@@ -1,60 +1,61 @@
-"""Low-overhead span tracer: context-manager/decorator API, thread-aware,
-monotonic-clocked, ring-buffered, Chrome-trace/Perfetto export.
+"""Span tracer on the profiler's clock: context-manager/decorator API,
+thread-aware, with exact per-name aggregates.
 
-The serving stack (engine stages, orchestrator loop, speculative rounds,
-page allocator) opens *spans* around units of work::
+The serving stack (engine stages, the engine's decode tick and
+admission, the orchestrator loop, speculative rounds, the page
+allocator) opens *spans* around units of work::
 
-    tracer = Tracer(enabled=True)
-    with tracer.span("generate.dispatch", cat="engine"):
-        out = generate_fn(params, state)
+    tracer = Tracer()
+    with tracer.span("engine.sample"):
+        toks = sample(logits)
 
     @tracer.trace("detok", cat="detok")
     def detokenize(...): ...
 
 Design points:
 
-* **Disabled is (nearly) free.**  ``span()`` on a disabled tracer returns
-  a shared no-op context manager after one attribute check — no
-  allocation, no clock read.  The serving hot loop keeps its spans in
-  place permanently and pays < 1 µs/call when tracing is off (bounded by
-  ``tests/test_obs.py``).
-* **Monotonic clock.**  All stamps are ``time.perf_counter()`` — the
-  highest-resolution monotonic clock, system-wide on Linux, so stamps
-  compare across threads.  Never ``time.time()`` (not monotonic; NTP
-  steps corrupt durations).
-* **Thread-aware nesting.**  Each thread keeps its own span stack
-  (``threading.local``), so spans nest correctly per thread and a span's
-  *self time* (duration minus time spent in child spans) is computed
-  online at close.  Self times are the currency of the per-stage wall
-  clock attribution in :mod:`repro.obs.report`: summed over all spans of
-  one thread they tile the traced wall time exactly — no double counting
-  of a stage inside the loop segment that dispatched it.
-* **Bounded memory.**  Finished spans land in a ring buffer
-  (``collections.deque(maxlen=capacity)``) — old events fall off, but the
-  per-name *aggregates* (count / total / self seconds) are exact over the
-  whole run regardless of ring capacity.
-* **Chrome trace export.**  ``chrome_trace()`` emits the Trace Event
-  Format JSON (``ph: "X"`` complete events, µs timestamps, thread-name
-  metadata) that ``chrome://tracing`` and https://ui.perfetto.dev load
-  directly; engine stages are additionally wrapped in
-  ``jax.profiler.TraceAnnotation`` at the call site so host spans line up
-  with XLA device traces captured via ``jax.profiler``.
+* **One clock: the profiler's.**  While a profiler session is active
+  (``jax.profiler.start_trace``), every span is also a
+  ``jax.profiler.TraceAnnotation`` of the same name, its keyword args
+  as metadata.  The spans land in the same ``.xplane.pb`` as the
+  device's operations, on one clock, so an idle stretch of the device
+  can be named by the host work that held it.  A profiler session is
+  the switch: there is no flag and no environment variable.
+* **Exact aggregates while enabled.**  With ``Tracer.enabled`` each span
+  also adds to per-name aggregates (count / total / self seconds,
+  ``time.perf_counter``).  Each thread keeps its own span stack, so a
+  span's *self time* (duration minus time in child spans on the same
+  thread) is computed online at close.  Self times are the currency of
+  :func:`repro.obs.report.stage_breakdown`: summed over one thread's
+  spans they tile its traced wall time without double counting.
+* **Off is (nearly) free.**  With neither on, ``span()`` returns a
+  shared no-op context manager after one attribute check and the
+  profiler's ``is_enabled`` check (~0.1 µs); the serving hot loop keeps
+  its spans in place permanently (bounded by ``tests/test_obs.py``).
+* **Collections are spans too.**  Importing this module installs, once
+  per process, a ``gc.callbacks`` hook that opens a ``host.gc`` span
+  (generation as metadata) around every Python collection while a
+  profiler session is active; with none it costs the ``is_enabled``
+  check a collection.
 """
 from __future__ import annotations
 
 import functools
-import json
-import os
+import gc
 import threading
-from collections import deque
 from time import perf_counter
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Tracer", "Span"]
+
+#: True while a profiler session is recording host spans
+profiling: Callable[[], bool] = TraceAnnotation.is_enabled
 
 
 class _NullSpan:
-    """Shared no-op context manager returned while tracing is disabled."""
+    """Shared no-op context manager returned while tracing is off."""
     __slots__ = ()
 
     def __enter__(self):
@@ -70,11 +71,13 @@ NULL_SPAN = _NULL_SPAN
 
 
 class Span:
-    """One live span; use via ``with tracer.span(...)``, not directly."""
-    __slots__ = ("_tracer", "name", "cat", "args", "t0", "t1", "_child_s")
+    """One live span of an enabled tracer; use via ``with tracer.span(...)``,
+    not directly."""
+    __slots__ = ("_tracer", "name", "cat", "args", "t0", "_child_s",
+                 "_note")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Optional[Dict[str, Any]]):
+                 args: Dict[str, Any]):
         self._tracer = tracer
         self.name = name
         self.cat = cat
@@ -82,46 +85,67 @@ class Span:
         self._child_s = 0.0
 
     def __enter__(self) -> "Span":
+        self._note = (TraceAnnotation(self.name, **self.args).__enter__()
+                      if profiling() else None)
         self._tracer._stack().append(self)
         self.t0 = perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self.t1 = perf_counter()
+        dur = perf_counter() - self.t0
         stack = self._tracer._stack()
         # tolerate misuse (exit out of order) without corrupting siblings
         if stack and stack[-1] is self:
             stack.pop()
-        dur = self.t1 - self.t0
         if stack:
             stack[-1]._child_s += dur
-        self._tracer._record(self, dur, dur - self._child_s)
+        self._tracer._add(self.name, self.cat, dur, dur - self._child_s)
+        if self._note is not None:
+            self._note.__exit__(*exc)
         return False
 
 
-class Tracer:
-    """Span recorder: ring buffer of events + exact per-name aggregates."""
+_gc_open = threading.local()
 
-    def __init__(self, capacity: int = 65536, enabled: bool = False):
-        if capacity < 1:
-            raise ValueError(f"capacity must be >= 1, got {capacity}")
+
+def _gc_span(phase: str, info: Dict[str, Any]) -> None:
+    """``gc.callbacks`` hook: a ``host.gc`` profiler span around each
+    collection.  A collection starts and stops on one thread, so the
+    open span is kept per thread."""
+    if phase == "start":
+        if profiling():
+            _gc_open.span = TraceAnnotation(
+                "host.gc", generation=info["generation"]).__enter__()
+        return
+    span = getattr(_gc_open, "span", None)
+    if span is not None:
+        _gc_open.span = None
+        span.__exit__(None, None, None)
+
+
+gc.callbacks.append(_gc_span)
+
+
+class Tracer:
+    """Spans to the profiler while a session is active; exact per-name
+    aggregates while ``enabled``."""
+
+    def __init__(self, enabled: bool = False):
         self.enabled = enabled
-        self.capacity = capacity
-        self._ring: deque = deque(maxlen=capacity)
-        # (name, cat) -> [count, total_s, self_s]; exact even on overflow
+        # (name, cat) -> [count, total_s, self_s]
         self._agg: Dict[Any, List[float]] = {}
         self._lock = threading.Lock()
         self._tls = threading.local()
-        self._threads: Dict[int, str] = {}
-        self._epoch = perf_counter()
-        self._pid = os.getpid()
 
     # ---- recording ----
     def span(self, name: str, cat: str = "host", **args) -> Any:
-        """Open a span; returns a context manager.  No-op when disabled."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return Span(self, name, cat, args or None)
+        """Open a span; returns a context manager.  No-op when neither
+        enabled nor profiling."""
+        if self.enabled:
+            return Span(self, name, cat, args)
+        if profiling():
+            return TraceAnnotation(name, **args)
+        return _NULL_SPAN
 
     def trace(self, name: Optional[str] = None,
               cat: str = "host") -> Callable:
@@ -131,55 +155,33 @@ class Tracer:
 
             @functools.wraps(fn)
             def wrapper(*a, **kw):
-                if not self.enabled:
-                    return fn(*a, **kw)
-                with Span(self, label, cat, None):
+                with self.span(label, cat):
                     return fn(*a, **kw)
             return wrapper
         return deco
 
-    def record(self, name: str, t0: float, t1: float, cat: str = "host",
-               **args) -> None:
-        """Record an already-closed span from external ``perf_counter``
+    def record(self, name: str, t0: float, t1: float,
+               cat: str = "host") -> None:
+        """Add an already-closed span from external ``perf_counter``
         stamps (e.g. a request's queue wait measured between its submit
-        and admit stamps).  No stack interaction: the span never nests,
-        so its self time equals its duration, and — unlike ``span()`` —
-        it does not subtract from any live parent span.  Use ``cat`` to
-        pick the attribution bucket (``"queue"`` spans are reported
-        outside the wall-clock sum: a request waiting overlaps other
-        requests decoding)."""
-        if not self.enabled:
-            return
-        dur = t1 - t0
-        tid = threading.get_ident()
-        t = threading.current_thread()
-        key = (name, cat)
-        with self._lock:
-            self._threads.setdefault(tid, t.name)
-            self._ring.append((name, cat, tid, t0, t1, args or None))
-            agg = self._agg.get(key)
-            if agg is None:
-                self._agg[key] = [1, dur, dur]
-            else:
-                agg[0] += 1
-                agg[1] += dur
-                agg[2] += dur
+        and admit stamps) to the aggregates.  No stack interaction: the
+        span never nests, so its self time equals its duration, and —
+        unlike ``span()`` — it does not subtract from any live parent
+        span.  Use ``cat`` to pick the attribution bucket (``"queue"``
+        spans are reported outside the wall-clock sum: a request waiting
+        overlaps other requests decoding)."""
+        if self.enabled:
+            self._add(name, cat, t1 - t0, t1 - t0)
 
     def _stack(self) -> List[Span]:
         stack = getattr(self._tls, "stack", None)
         if stack is None:
             stack = self._tls.stack = []
-            t = threading.current_thread()
-            with self._lock:
-                self._threads[t.ident] = t.name
         return stack
 
-    def _record(self, span: Span, dur: float, self_s: float) -> None:
-        tid = threading.get_ident()
-        key = (span.name, span.cat)
+    def _add(self, name: str, cat: str, dur: float, self_s: float) -> None:
+        key = (name, cat)
         with self._lock:
-            self._ring.append((span.name, span.cat, tid, span.t0, span.t1,
-                               span.args))
             agg = self._agg.get(key)
             if agg is None:
                 self._agg[key] = [1, dur, self_s]
@@ -196,22 +198,13 @@ class Tracer:
         self.enabled = False
 
     def reset(self) -> None:
-        """Drop recorded events and aggregates (enabled flag unchanged)."""
+        """Drop the aggregates (enabled flag unchanged)."""
         with self._lock:
-            self._ring.clear()
             self._agg.clear()
-            self._epoch = perf_counter()
 
-    # ---- inspection / export ----
-    def events(self) -> List[Dict[str, Any]]:
-        """Finished spans still in the ring buffer, oldest first."""
-        with self._lock:
-            raw = list(self._ring)
-        return [{"name": n, "cat": c, "tid": tid, "t0": t0, "t1": t1,
-                 "args": args} for n, c, tid, t0, t1, args in raw]
-
+    # ---- inspection ----
     def self_times(self) -> Dict[str, Dict[str, Any]]:
-        """Exact per-span-name aggregates over the whole run:
+        """Exact per-span-name aggregates while enabled:
         ``{name: {cat, count, total_s, self_s}}``.  ``self_s`` excludes
         time spent inside child spans, so summing it across names never
         double-counts nested work."""
@@ -228,28 +221,3 @@ class Tracer:
                 rec["total_s"] += total
                 rec["self_s"] += self_s
         return out
-
-    def chrome_trace(self) -> Dict[str, Any]:
-        """Trace Event Format dict (load in chrome://tracing / Perfetto)."""
-        events: List[Dict[str, Any]] = []
-        with self._lock:
-            raw = list(self._ring)
-            threads = dict(self._threads)
-            epoch = self._epoch
-        for name, cat, tid, t0, t1, args in raw:
-            ev: Dict[str, Any] = {
-                "name": name, "cat": cat, "ph": "X", "pid": self._pid,
-                "tid": tid, "ts": (t0 - epoch) * 1e6,
-                "dur": (t1 - t0) * 1e6}
-            if args:
-                ev["args"] = args
-            events.append(ev)
-        for tid, tname in threads.items():
-            events.append({"name": "thread_name", "ph": "M",
-                           "pid": self._pid, "tid": tid,
-                           "args": {"name": tname}})
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-    def write_chrome_trace(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)

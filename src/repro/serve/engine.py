@@ -367,45 +367,47 @@ class ServingEngine:
         bucketed prefill over every admitted prompt and insert per row.
         Returns per-request admission flags; reservation stops at the
         first request that doesn't fit (FIFO order is preserved)."""
-        toks = [self._admission_tokens(r) for r in reqs]
-        admitted: List[Tuple[Request, int, Any, int]] = []
-        ok = [False] * len(reqs)
-        for j, req in enumerate(reqs):
-            if not self.engine.bucketed and admitted:
-                break   # exact-length prefill: one prompt per call
-            r = self._reserve(req)
-            if r is None:
-                break   # no slot/pages: later entries wait for this one
-            admitted.append((req, r[0], r[1], j))
-            ok[j] = True
-        if not admitted:
+        tr = self.tracer
+        with tr.span("engine.admit"):
+            toks = [self._admission_tokens(r) for r in reqs]
+            admitted: List[Tuple[Request, int, Any, int]] = []
+            ok = [False] * len(reqs)
+            with tr.span("engine.pages"):
+                for j, req in enumerate(reqs):
+                    if not self.engine.bucketed and admitted:
+                        break   # exact-length prefill: one prompt per call
+                    r = self._reserve(req)
+                    if r is None:
+                        break   # no slot/pages: later entries wait
+                    admitted.append((req, r[0], r[1], j))
+                    ok[j] = True
+            if not admitted:
+                return ok
+            now = time.perf_counter()
+            for req, _, _, _ in admitted:
+                sub = req.timing.setdefault("submit", now)
+                if "admit" not in req.timing:   # first admission only: a
+                    req.timing["admit"] = now   # readmit isn't a queue wait
+                    if now > sub:
+                        tr.record("queue.wait", sub, now, cat="queue")
+            if self.engine.bucketed:
+                bucket = self.engine.bucket_for(
+                    max(len(toks[j]) for _, _, _, j in admitted))
+                pad = np.zeros((len(admitted), bucket), np.int32)
+                lens = np.zeros(len(admitted), np.int32)
+                for row, (_, _, _, j) in enumerate(admitted):
+                    pad[row, :len(toks[j])] = toks[j]
+                    lens[row] = len(toks[j])
+                prefix = self.engine.prefill(self.params, pad, lens)
+            else:
+                (_, _, _, j0) = admitted[0]
+                prefix = self.engine.prefill(
+                    self.params, np.asarray(toks[j0], np.int32)[None])
+            done = time.perf_counter()
+            for row, (req, slot, dst_rows, _) in enumerate(admitted):
+                req.timing.setdefault("prefill_done", done)
+                self._install(req, slot, dst_rows, prefix, row)
             return ok
-        now = time.perf_counter()
-        for req, _, _, _ in admitted:
-            sub = req.timing.setdefault("submit", now)
-            if "admit" not in req.timing:   # first admission only: a
-                req.timing["admit"] = now   # readmit isn't a queue wait
-                if self.tracer.enabled and now > sub:
-                    self.tracer.record("queue.wait", sub, now, cat="queue",
-                                       uid=req.uid)
-        if self.engine.bucketed:
-            bucket = self.engine.bucket_for(max(len(toks[j])
-                                                for _, _, _, j in admitted))
-            pad = np.zeros((len(admitted), bucket), np.int32)
-            lens = np.zeros(len(admitted), np.int32)
-            for row, (_, _, _, j) in enumerate(admitted):
-                pad[row, :len(toks[j])] = toks[j]
-                lens[row] = len(toks[j])
-            prefix = self.engine.prefill(self.params, pad, lens)
-        else:
-            (_, _, _, j0) = admitted[0]
-            prefix = self.engine.prefill(
-                self.params, np.asarray(toks[j0], np.int32)[None])
-        done = time.perf_counter()
-        for row, (req, slot, dst_rows, _) in enumerate(admitted):
-            req.timing.setdefault("prefill_done", done)
-            self._install(req, slot, dst_rows, prefix, row)
-        return ok
 
     def _worst_pages(self, req: Request) -> int:
         """Worst-case page demand of ``req``: its admission tokens plus
@@ -531,54 +533,65 @@ class ServingEngine:
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         if not active:
             return
-        if self.paged:
-            # grow page lists so every active slot has a page for the
-            # token this tick writes at its own position
-            self._grow_pages(active, lambda i: self.slot_pos[i] + 1)
-            active = [i for i in active if self.slot_req[i] is not None]
-            if not active:
-                return
-        self.cache["tok"] = jnp.asarray(self.last_tok)
-        # guard-armed engines retain the pre-generate state (donate=False)
-        # so a quarantined slot can be re-decoded up the precision ladder
-        prev = self.cache if self.guard is not None else None
-        self.cache, logits = self.engine.generate(self.params, self.cache)
-        logits = np.asarray(logits)
-        if self.faults is not None or self.guard is not None:
-            logits = np.array(logits, copy=True)   # writable host copy
-            poisons = {}
-            if self.faults is not None:
-                poisons = self.faults.poison_round(
-                    {i: self.slot_req[i].uid for i in active})
-                for i in poisons:
-                    logits[i] = np.nan
-            if self.guard is not None:
-                self.guard.check_round(prev, logits, active, poisons)
-                # ladder-exhausted requests terminated inside the guard:
-                # reclaim their slot + pages, drop them from this round
+        tr = self.tracer
+        with tr.span("engine.step"):
+            with tr.span("engine.pages"):
+                if self.paged:
+                    # grow page lists so every active slot has a page for
+                    # the token this tick writes at its own position
+                    self._grow_pages(active, lambda i: self.slot_pos[i] + 1)
+                    active = [i for i in active
+                              if self.slot_req[i] is not None]
+                    if not active:
+                        return
+                self.cache["tok"] = jnp.asarray(self.last_tok)
+            # guard-armed engines retain the pre-generate state
+            # (donate=False) so a quarantined slot can be re-decoded up
+            # the precision ladder
+            prev = self.cache if self.guard is not None else None
+            self.cache, logits = self.engine.generate(self.params,
+                                                      self.cache)
+            hooked = self.faults is not None or self.guard is not None
+            with tr.span("engine.logits"):
+                logits = np.asarray(logits)
+                if hooked:
+                    logits = np.array(logits, copy=True)   # writable copy
+            if hooked:
+                poisons = {}
+                if self.faults is not None:
+                    poisons = self.faults.poison_round(
+                        {i: self.slot_req[i].uid for i in active})
+                    for i in poisons:
+                        logits[i] = np.nan
+                if self.guard is not None:
+                    self.guard.check_round(prev, logits, active, poisons)
+                    # ladder-exhausted requests terminated inside the
+                    # guard: reclaim their slot + pages, drop them from
+                    # this round
+                    for i in active:
+                        r = self.slot_req[i]
+                        if r is not None and r.done:
+                            self._free_request_slot(i)
+                    active = [i for i in active
+                              if self.slot_req[i] is not None]
+            with tr.span("engine.sample"):
+                temps = np.asarray([0.0 if r is None else self._req_temp(r)
+                                    for r in self.slot_req], np.float32)
+                toks = self._sample(logits, temps)
+            self.stats["decode_steps"] += 1
+            with tr.span("engine.emit"):
                 for i in active:
-                    r = self.slot_req[i]
-                    if r is not None and r.done:
+                    req = self.slot_req[i]
+                    tok = int(toks[i])
+                    self.last_tok[i, 0] = tok
+                    self.slot_pos[i] += 1
+                    self._emit(req, [tok])
+                    eos = self.scfg.eos_id
+                    if (len(req.out_tokens) >= req.max_new
+                            or (eos is not None and tok == eos)
+                            or self.slot_pos[i] >= self.scfg.max_len - 1):
+                        req.done = True
                         self._free_request_slot(i)
-                active = [i for i in active
-                          if self.slot_req[i] is not None]
-        temps = np.asarray([0.0 if r is None else self._req_temp(r)
-                            for r in self.slot_req], np.float32)
-        with self.tracer.span("host.sample"):
-            toks = self._sample(logits, temps)
-        self.stats["decode_steps"] += 1
-        for i in active:
-            req = self.slot_req[i]
-            tok = int(toks[i])
-            self.last_tok[i, 0] = tok
-            self.slot_pos[i] += 1
-            self._emit(req, [tok])
-            eos = self.scfg.eos_id
-            if (len(req.out_tokens) >= req.max_new
-                    or (eos is not None and tok == eos)
-                    or self.slot_pos[i] >= self.scfg.max_len - 1):
-                req.done = True
-                self._free_request_slot(i)
 
     def abort(self, req: Request, error: Optional[str] = None) -> None:
         """Terminally release ``req`` from outside the decode loop

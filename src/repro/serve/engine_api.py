@@ -40,15 +40,14 @@ Families outside the bucketed gate (sliding-window, recurrent/SSM, MoE,
 audio/vlm) keep the legacy exact-length full-width prefill + whole-leaf
 insert path, preserving their semantics unchanged.
 
-Observability: constructed with a ``tracer``/``metrics`` pair
-(:mod:`repro.obs`), every stage call is wrapped in *paired* stamps — a
-``<stage>.dispatch`` span until the (async) stage call returns to
-Python, then a ``<stage>.device`` span around ``jax.block_until_ready``
-— so Python/jit-dispatch overhead is attributed separately from device
-compute, and a ``jax.profiler.TraceAnnotation`` so host spans line up
-with XLA traces.  With tracing disabled nothing is synchronized and the
-per-call overhead is a single attribute check (the < 2 % decode-loop
-bound in ``tests/test_obs.py``).
+Observability: every stage call is one ``stage.<prefix><stage>`` span
+(cat ``engine``, :mod:`repro.obs`) around its dispatch, with no sync: it
+lands in a profiler trace beside the stage's device programs whenever a
+profiler session is active, so device time per stage is the device
+trace's to give.  With the tracer enabled the dispatch time also feeds
+the ``stage.<name>.dispatch_s`` histogram.  Always on: the
+``stage.<name>.calls`` counters.  With tracing off a stage call is a
+plain call after the profiler's ``is_enabled`` check.
 """
 from __future__ import annotations
 
@@ -64,6 +63,7 @@ from ..core.transprecision import TCPolicy, get_policy
 from ..models.serve_model import (decode_step, init_cache, prefill,
                                   verify_step)
 from ..obs import MetricsRegistry, Tracer
+from ..obs.tracer import profiling
 
 _POOL_LEAF_NAMES = ("k", "v", "k_scale", "v_scale")
 _SCRUB_LEAVES = ("k", "v", "k_scale", "v_scale")
@@ -220,10 +220,11 @@ class TransprecisionEngine:
         # failures with bounded exponential backoff (serve/faults.py)
         self.faults = faults
         self.retry = retry
-        # observability: spans + per-stage latency histograms while the
-        # tracer is enabled (the speculative draft engine shares its
-        # driver's tracer/registry under a "draft." stage prefix)
-        self.tracer = tracer
+        # observability: a span per stage call, and dispatch-time
+        # histograms while the tracer is enabled (the speculative draft
+        # engine shares its driver's tracer/registry under a "draft."
+        # stage prefix)
+        self.tracer = tracer if tracer is not None else Tracer()
         self.metrics = metrics
         self.stage_prefix = stage_prefix
         self.max_batch, self.max_len = max_batch, max_len
@@ -269,12 +270,12 @@ class TransprecisionEngine:
 
     # ---- observability ----
     def _staged(self, stage: str, fn, *args):
-        """Run one engine stage with paired host-dispatch / device-
-        complete stamps.  The dispatch span covers the Python call (jit
-        dispatch, and compilation on a cache miss); the device span
-        covers the ``block_until_ready`` wait for the stage's outputs.
-        With no enabled tracer this is a plain call — no sync, no
-        stamps — so tracing-off serving keeps XLA's async dispatch."""
+        """Run one engine stage inside its ``stage.<name>`` span.  The
+        span covers the Python call (jit dispatch, and compilation on a
+        cache miss); nothing waits for the device, so serving keeps
+        XLA's async dispatch whether or not it is traced.  Untraced (tracer
+        disabled, no profiler session) it is a plain call: no span
+        object, no extra frame."""
         name = self.stage_prefix + stage
         if self.metrics is not None:
             ctr = self._call_counters.get(name)
@@ -285,23 +286,16 @@ class TransprecisionEngine:
         if name not in self.stage_specs:
             self.stage_specs[name] = (fn, _abstract_args(args))
         tr = self.tracer
-        if tr is None or not tr.enabled:
+        if not tr.enabled and not profiling():
             if self.faults is None and self.retry is None:
                 return fn(*args)
             return self._invoke(name, fn, args)
         t0 = perf_counter()
-        with jax.profiler.TraceAnnotation(name):
-            with tr.span(name + ".dispatch", cat="engine"):
-                out = self._invoke(name, fn, args)
-        t1 = perf_counter()
-        with tr.span(name + ".device", cat="engine"):
-            jax.block_until_ready(out)
-        t2 = perf_counter()
-        if self.metrics is not None:
+        with tr.span("stage." + name, cat="engine"):
+            out = self._invoke(name, fn, args)
+        if self.metrics is not None and tr.enabled:
             self.metrics.histogram(f"stage.{name}.dispatch_s").observe(
-                t1 - t0)
-            self.metrics.histogram(f"stage.{name}.device_s").observe(
-                t2 - t1)
+                perf_counter() - t0)
         return out
 
     def _invoke(self, name, fn, args):
@@ -488,10 +482,11 @@ class TransprecisionEngine:
         t = chunk.shape[1]
         fn = self._verify_jits.get(t)
         if fn is None:
-            def impl(p, c, tk):
+            def verify_impl(p, c, tk):
                 logits, nc = verify_step(p, c, tk, self.cfg, self.policy)
                 return nc, logits
-            fn = jax.jit(impl, donate_argnums=(1,) if self._donate else ())
+            fn = jax.jit(verify_impl,
+                         donate_argnums=(1,) if self._donate else ())
             self._verify_jits[t] = fn
         return self._staged("verify", fn, params, state, chunk)
 
