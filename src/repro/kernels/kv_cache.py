@@ -232,29 +232,40 @@ def flash_block(q_ref, kc_ref, ks_ref, vc_ref, vs_ref, m_ref, l_ref,
     masked (their V rows are zeroed too, so stale or padded codes cannot
     leak a NaN into the sum).  Decode-on-read: each head's posit codes
     become f32 in VMEM right before the MXU consumes them."""
-    nkv = q_ref.shape[0]
-    rows = kc_ref.shape[0]
-
     @pl.when(first)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        flash_init(m_ref, l_ref, acc_ref)
+
+    flash_rows(q_ref, [kc_ref], [ks_ref], [vc_ref], [vs_ref], m_ref, l_ref,
+               acc_ref, n_valid, fmt=fmt, packed=packed)
+
+
+def flash_rows(q_ref, kc_refs, ks_refs, vc_refs, vs_refs, m_ref, l_ref,
+               acc_ref, n_valid, *, fmt, packed):
+    """``flash_block``'s update over a block whose rows are those of the
+    listed refs, in order (the paged kernel's pages of one block): each
+    head's codes and scales are joined along the rows, then decoded and
+    reduced as one tile."""
+    nkv = q_ref.shape[0]
+    rows = sum(r.shape[0] for r in kc_refs)
+
+    def join(parts):
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
 
     col_ok = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1) < n_valid
     row_ok = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) < n_valid
     for j in range(nkv):
-        kc = kc_ref[:, j, :].astype(jnp.int32)                    # (rows, Dc)
-        vc = vc_ref[:, j, :].astype(jnp.int32)
+        kc = join([r[:, j, :].astype(jnp.int32) for r in kc_refs])  # (rows, Dc)
+        vc = join([r[:, j, :].astype(jnp.int32) for r in vc_refs])
         if packed:
             kc, vc = unpack_nibbles(kc), unpack_nibbles(vc)
-        k = decode_tile(kc, fmt) * ks_ref[:, pl.ds(j, 1)]         # (rows, hd)
-        v = decode_tile(vc, fmt) * vs_ref[:, pl.ds(j, 1)]
-        v = jnp.where(row_ok, v, 0.0)
-        q = q_ref[j].astype(jnp.float32)                          # (grp, hd)
+        k = decode_tile(kc, fmt) * join([r[:, pl.ds(j, 1)] for r in ks_refs])
+        v = decode_tile(vc, fmt) * join([r[:, pl.ds(j, 1)] for r in vs_refs])
+        v = jnp.where(row_ok, v, 0.0)                               # (rows, hd)
+        q = q_ref[j].astype(jnp.float32)                            # (grp, hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = jnp.where(col_ok, s, NEG_INF)                         # (grp, rows)
+        s = jnp.where(col_ok, s, NEG_INF)                           # (grp, rows)
         m_prev = m_ref[j]
         m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -263,6 +274,12 @@ def flash_block(q_ref, kc_ref, ks_ref, vc_ref, vs_ref, m_ref, l_ref,
         acc_ref[j] = acc_ref[j] * corr + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[j] = m_new
+
+
+def flash_init(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
 def flash_finish(o_ref, l_ref, acc_ref):

@@ -23,12 +23,12 @@ Layout (per attention layer; no batch axis — pages are shared):
       (nkv, Dc) code rows move from VMEM to HBM and the code buffers are
       donated (``input_output_aliases``), exactly like the ring
       ``kv_append``; the scales go through an XLA scatter.
-  read path   ``paged_decode_attention`` — the grid's innermost dim walks
-      the sequence's page list: the page-table row is scalar-prefetched
-      and the *index map* uses it to DMA whole (ps, nkv, Dc) physical
-      pages into VMEM, where posit tiles are decoded right before the
-      online-softmax MACs.
-      (m, l, acc) live in VMEM scratch across the page walk.
+  read path   ``paged_decode_attention`` — one grid step per live block
+      of pages, slot after slot: the page table is scalar-prefetched and
+      the *index maps* use it to DMA whole (ps, nkv, Dc) physical pages
+      into VMEM, where posit tiles are decoded right before the
+      online-softmax MACs.  Pages past a slot's length are never read.
+      (m, l, acc) live in VMEM scratch across a slot's blocks.
 
 Pure-jnp references (``paged_kv_append_ref`` / ``paged_decode_attention_ref``
 / ``gather_pages``) share the codec with the kernels, so CPU serving and
@@ -47,7 +47,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import PositFormat
 from .kv_cache import (decode_kv_rows, encode_kv_rows, encode_scaled_rows,
-                       flash_block, flash_finish, flash_scratch, scale_rows)
+                       flash_finish, flash_init, flash_rows, flash_scratch,
+                       scale_rows)
 
 
 def flat_dst_rows(page_table, pos, page_size: int):
@@ -178,19 +179,52 @@ def paged_kv_append_rows_ref(k_codes, k_scale, v_codes, v_scale, k_new,
 # paged_decode_attention: page-walking fused decode (Pallas)
 # ---------------------------------------------------------------------------
 
-def _paged_attn_kernel(tbl_ref, len_ref, q_ref, kc_ref, ks_ref, vc_ref,
-                       vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                       fmt, packed, ps, npg):
-    del tbl_ref  # consumed by the index maps (page DMA addressing)
-    bi = pl.program_id(0)
-    pi = pl.program_id(1)
-    flash_block(q_ref, kc_ref, ks_ref, vc_ref, vs_ref, m_ref, l_ref,
-                 acc_ref, pi == 0, len_ref[bi] - pi * ps, fmt=fmt,
-                 packed=packed)
+BLOCK_ROWS = 128   # pool rows a grid step of the page walk covers
 
-    @pl.when(pi == npg - 1)
+
+def pages_per_block(page_size: int, pmax: int) -> int:
+    """Pages one grid step of ``paged_decode_attention`` covers: about
+    ``BLOCK_ROWS`` rows, never more pages than the table holds."""
+    return max(1, min(BLOCK_ROWS // page_size, pmax))
+
+
+def _paged_attn_kernel(tbl_ref, len_ref, slot_ref, blk_ref, q_ref, *refs,
+                       fmt, packed, ps, ppb):
+    del tbl_ref  # consumed by the index maps (page DMA addressing)
+    kc, ks, vc, vs = (refs[a * ppb:(a + 1) * ppb] for a in range(4))
+    o_ref, m_ref, l_ref, acc_ref = refs[4 * ppb:]
+    step = pl.program_id(0)
+    blk = blk_ref[step]
+    n_valid = len_ref[slot_ref[step]] - blk * ppb * ps
+
+    @pl.when(blk == 0)
+    def _init():
+        flash_init(m_ref, l_ref, acc_ref)
+
+    @pl.when(n_valid > 0)
+    def _live():
+        flash_rows(q_ref, kc, ks, vc, vs, m_ref, l_ref, acc_ref, n_valid,
+                   fmt=fmt, packed=packed)
+
+    @pl.when(n_valid <= ppb * ps)           # the slot's last block
     def _finish():
         flash_finish(o_ref, l_ref, acc_ref)
+
+
+def _page_index(j, ps, ppb):
+    """Index map of a block's ``j``-th page: the physical page of logical
+    page ``blk * ppb + j`` of the step's slot.  Past the slot's last live
+    page it names the page the same operand held in the block before, so
+    the pipeline issues no DMA for it; in a slot's first block, which has
+    no block before, it names the last live page."""
+    def index(step, t, ln, slot, blk):
+        i, blk = slot[step], blk[step]
+        last = jnp.maximum(ln[i] - 1, 0) // ps          # last live page
+        page = blk * ppb + j
+        page = jnp.where(page <= last, page,
+                         jnp.where(blk > 0, page - ppb, last))
+        return t[i, page]
+    return index
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "page_size", "packed",
@@ -201,14 +235,20 @@ def paged_decode_attention(q, k_codes, k_scale, v_codes, v_scale,
                            interpret=None):
     """Fused one-token GQA attention over a paged posit pool.
 
-    q: (B, 1, nh, hd); k/v_codes: (R, nkv, Dc) pool; k/v_scale: (R, nkv);
-    page_table: (B, Pmax) i32 (entries must be valid physical pages —
-    unallocated logical pages point at the trash page and are masked by
-    ``seq_lens``); seq_lens: (B,) i32.  The grid's innermost dimension
-    walks the Pmax page-table entries of each slot; each step DMAs one
-    whole (page_size, nkv, Dc) page (all KV heads) with (m, l, acc)
-    carried in VMEM scratch.  On the TPU ``page_size`` must divide by 8.
-    Returns (B, 1, nh, hd)."""
+    q: (B, 1, nh, hd); k/v_codes: (R, nkv, Dc) pool; k/v_scale:
+    (R, nkv); page_table: (B, Pmax) i32 (entries must be valid physical
+    pages — unallocated logical pages point at the trash page);
+    seq_lens: (B,) i32.  The grid has one step per live block of
+    ``pages_per_block`` pages, slot after slot (its size is worked out
+    from ``seq_lens`` on the device), so the cost follows each slot's
+    live KV, not Pmax.  Each page of a block is its own operand, whose
+    index map reads the scalar-prefetched table, so the pipeline DMAs
+    whole (page_size, nkv, Dc) pages of all KV heads while the step
+    before computes; a page past the slot's length repeats what its
+    operand already holds, so no DMA is issued for it.  A block runs one
+    online-softmax update over all its rows, (m, l, acc) carried in VMEM
+    scratch.  On the TPU ``page_size`` must divide by 8.  Returns
+    (B, 1, nh, hd)."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     r, nkv, dc = k_codes.shape
@@ -216,25 +256,43 @@ def paged_decode_attention(q, k_codes, k_scale, v_codes, v_scale,
     grp = nh // nkv
     npg = page_table.shape[1]
     num_pages = r // page_size
-    tbl = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, num_pages - 1)
-    lens = jnp.broadcast_to(jnp.asarray(seq_lens, jnp.int32), (b,))
-    qg = (q.reshape(b, nkv, grp, hd) * (hd ** -0.5)).astype(jnp.float32)
     ps = page_size
+    ppb = pages_per_block(ps, npg)
+    bk = ppb * ps
+    tbl = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, num_pages - 1)
+    lens = jnp.minimum(
+        jnp.broadcast_to(jnp.asarray(seq_lens, jnp.int32), (b,)), npg * ps)
+    qg = (q.reshape(b, nkv, grp, hd) * (hd ** -0.5)).astype(jnp.float32)
+    # one grid step per live block, slot after slot; a slot with no live
+    # row still takes one step, which writes its (zero) output.  The
+    # pipeline reads the step arrays past the grid's last step: on a v5e
+    # with every slot's table full, one or two spare entries halted the
+    # core and eight did not.  The spares repeat the last block
+    nblk = jnp.maximum(-(-lens // bk), 1)
+    first = jnp.cumsum(nblk) - nblk
+    steps = jnp.arange(b * -(-npg // ppb) + 8, dtype=jnp.int32)
+    slot = jnp.sum(first[None, :] <= steps[:, None], axis=1,
+                   dtype=jnp.int32) - 1
+    blk = jnp.minimum(steps - first[slot], nblk[slot] - 1)
 
     sq = pl.Squeezed()
-    codes = pl.BlockSpec((ps, nkv, dc), lambda i, p, t, ln: (t[i, p], 0, 0))
-    scales = pl.BlockSpec((ps, nkv), lambda i, p, t, ln: (t[i, p], 0))
-    heads = pl.BlockSpec((sq, nkv, grp, hd), lambda i, p, t, ln: (i, 0, 0, 0))
+    heads = pl.BlockSpec((sq, nkv, grp, hd),
+                         lambda s, t, ln, sl, bl: (sl[s], 0, 0, 0))
+    codes = [pl.BlockSpec((ps, nkv, dc), lambda *a, f=_page_index(
+        j, ps, ppb): (f(*a), 0, 0)) for j in range(ppb)]
+    scales = [pl.BlockSpec((ps, nkv), lambda *a, f=_page_index(
+        j, ps, ppb): (f(*a), 0)) for j in range(ppb)]
     out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, fmt=fmt, packed=packed,
-                          ps=ps, npg=npg),
+                          ps=ps, ppb=ppb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b, npg),
-            in_specs=[heads, codes, scales, codes, scales],
+            num_scalar_prefetch=4, grid=(jnp.sum(nblk),),
+            in_specs=[heads] + codes + scales + codes + scales,
             out_specs=heads, scratch_shapes=flash_scratch(nkv, grp, hd)),
         out_shape=jax.ShapeDtypeStruct((b, nkv, grp, hd), jnp.float32),
         interpret=interpret,
-    )(tbl, lens, qg, k_codes, k_scale, v_codes, v_scale)
+    )(tbl, lens, slot, blk, qg, *[k_codes] * ppb, *[k_scale] * ppb,
+      *[v_codes] * ppb, *[v_scale] * ppb)
     return out.reshape(b, 1, nh, hd).astype(q.dtype)
 
 

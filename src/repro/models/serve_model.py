@@ -218,14 +218,16 @@ def _attn_decode_paged(c, cfg, policy, pos, qp, kp, vp, table, attn_impl):
             v_read = paged_kernels.gather_decode_pages(
                 vc, vs, table, ps, spec.fmt, spec.packed)
             ao = attn_impl(qp, k_read, v_read, seq_lens)
-        elif jax.default_backend() == "cpu":
-            ao = paged_kernels.paged_decode_attention_ref(
-                qp, kc, ks, vc, vs, table, seq_lens, spec.fmt,
-                page_size=ps, packed=spec.packed)
         else:
-            ao = paged_kernels.paged_decode_attention(
-                qp, kc, ks, vc, vs, table, seq_lens, spec.fmt,
-                page_size=ps, packed=spec.packed)
+            # an idle slot holds no page (its table row starts at the
+            # trash page) while its position runs on: it attends to no
+            # row, so the page walk skips it
+            live = jnp.where(table[:, 0] > 0, seq_lens, 0)
+            attend = (paged_kernels.paged_decode_attention_ref
+                      if jax.default_backend() == "cpu"
+                      else paged_kernels.paged_decode_attention)
+            ao = attend(qp, kc, ks, vc, vs, table, live, spec.fmt,
+                        page_size=ps, packed=spec.packed)
         new_c.update(k=kc, v=vc, k_scale=ks, v_scale=vs)
     else:
         kc = c["k"].at[dst].set(kp[:, 0].astype(c["k"].dtype))

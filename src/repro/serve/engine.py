@@ -209,7 +209,8 @@ class ServingEngine:
         # (every key is a registry counter/gauge named "engine.<key>")
         self.stats = StatsView(self.metrics, prefix="engine.")
         self.stats.bind_counters("prefills", "decode_steps", "tokens",
-                                 "rejected", "evictions")
+                                 "rejected", "evictions", "kv_pages_live",
+                                 "kv_pages_table")
         self.stats.bind_gauges("peak_live_pages", "kv_cache_bytes")
         self.stats["kv_cache_bytes"] = self.kv_cache_bytes()
 
@@ -544,6 +545,15 @@ class ServingEngine:
                               if self.slot_req[i] is not None]
                     if not active:
                         return
+                    # the live pages of the active slots, which the
+                    # decode attention walks this tick, and the whole
+                    # page table: their ratio is the share of a walk of
+                    # every page that does work
+                    ps = self.allocator.page_size
+                    self.stats["kv_pages_live"] += sum(
+                        pages_for(int(self.slot_pos[i]) + 1, ps) for i in active)
+                    self.stats["kv_pages_table"] += (self.scfg.max_batch
+                                                     * self._pmax)
                 self.cache["tok"] = jnp.asarray(self.last_tok)
             # guard-armed engines retain the pre-generate state
             # (donate=False) so a quarantined slot can be re-decoded up

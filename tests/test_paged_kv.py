@@ -96,18 +96,50 @@ def test_paged_append_kernel_bit_exact(name, fmt, packed):
         kc, ks, vc, vs = got
 
 
-@pytest.mark.parametrize("name,fmt,packed", FMTS, ids=lambda x: str(x))
-@pytest.mark.parametrize("lens", [(1, 1, 1), (6, 12, 11), (3, 8, 12)])
-def test_paged_decode_attention_matches_ref(name, fmt, packed, lens):
-    rng = np.random.default_rng(3)
-    b, nkv, grp, hd, ps, npages = 3, 2, 2, 8, 4, 7
-    R = npages * ps
-    kf = rng.normal(0, 1, (R, nkv, hd)).astype(np.float32)
-    vf = rng.normal(0, 1, (R, nkv, hd)).astype(np.float32)
+# (page size, Pmax, lengths, table): the first three cases walk pages of 4
+# rows, one block each; at 16-row pages a block holds 8 pages (128 rows)
+ATTN_CASES = {
+    "ps4-len1": (4, 3, (1, 1, 1), "fixed"),
+    "ps4-mixed": (4, 3, (6, 12, 11), "fixed"),
+    "ps4-full": (4, 3, (3, 8, 12), "fixed"),
+    "len1": (16, 20, (1, 1, 1), "shuffled"),
+    "page-boundary": (16, 20, (16, 32, 48), "shuffled"),
+    "block-boundary": (16, 20, (128, 256, 127), "shuffled"),
+    "several-blocks": (16, 20, (300, 129, 257), "shuffled"),
+    "all-trash-slot": (16, 20, (40, 100, 5), "trash"),
+    "pmax-not-multiple": (16, 20, (320, 319, 260), "shuffled"),
+    "past-the-table": (16, 20, (5000, 321, 64), "shuffled"),
+    "pmax-below-block": (16, 3, (48, 17, 1), "shuffled"),
+}
+
+
+def _attn_pool(rng, fmt, packed, b, nkv, hd, ps, pmax, layout):
+    """A random posit pool and a page table of ``layout``: ``fixed`` (the
+    hand-written 3-page table), ``shuffled`` (physical pages in a random,
+    non-contiguous order) or ``trash`` (shuffled, slot 1 all trash)."""
+    npages = 1 + b * pmax
+    rows = npages * ps
+    kf = rng.normal(0, 1, (rows, nkv, hd)).astype(np.float32)
+    vf = rng.normal(0, 1, (rows, nkv, hd)).astype(np.float32)
     kc, ks = kvk.encode_kv_rows(jnp.asarray(kf), fmt, packed)
     vc, vs = kvk.encode_kv_rows(jnp.asarray(vf), fmt, packed)
-    ks, vs = ks[..., 0], vs[..., 0]
-    table = jnp.asarray([[1, 2, 0], [3, 4, 5], [6, 1, 2]], jnp.int32)
+    if layout == "fixed":
+        table = np.asarray([[1, 2, 0], [3, 4, 5], [6, 1, 2]])
+    else:
+        table = 1 + rng.permutation(b * pmax).reshape(b, pmax)
+        if layout == "trash":
+            table[1] = 0
+    return kc, ks[..., 0], vc, vs[..., 0], jnp.asarray(table, jnp.int32)
+
+
+@pytest.mark.parametrize("name,fmt,packed", FMTS, ids=lambda x: str(x))
+@pytest.mark.parametrize("case", list(ATTN_CASES))
+def test_paged_decode_attention_matches_ref(name, fmt, packed, case):
+    ps, pmax, lens, layout = ATTN_CASES[case]
+    rng = np.random.default_rng(3)
+    b, nkv, grp, hd = 3, 2, 2, 8
+    kc, ks, vc, vs, table = _attn_pool(rng, fmt, packed, b, nkv, hd, ps,
+                                       pmax, layout)
     q = jnp.asarray(rng.normal(0, 1, (b, 1, nkv * grp, hd)), jnp.float32)
     seq_lens = jnp.asarray(lens, jnp.int32)
     got = pkv.paged_decode_attention(q, kc, ks, vc, vs, table, seq_lens,
@@ -118,6 +150,36 @@ def test_paged_decode_attention_matches_ref(name, fmt, packed, lens):
                                           packed=packed)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("name,fmt,packed", FMTS, ids=lambda x: str(x))
+def test_paged_decode_attention_empty_slot_is_zero(name, fmt, packed):
+    """A slot of length 0 (an idle slot) reads no page and computes
+    nothing, yet its output is written: exact zeros, whatever the VMEM
+    buffers held from the slot before; its neighbours still match the
+    reference."""
+    rng = np.random.default_rng(4)
+    b, nkv, grp, hd, ps, pmax = 3, 2, 2, 8, 16, 20
+    kc, ks, vc, vs, table = _attn_pool(rng, fmt, packed, b, nkv, hd, ps,
+                                       pmax, "shuffled")
+    q = jnp.asarray(rng.normal(0, 1, (b, 1, nkv * grp, hd)), jnp.float32)
+    seq_lens = jnp.asarray([200, 0, 37], jnp.int32)
+    got = np.asarray(pkv.paged_decode_attention(
+        q, kc, ks, vc, vs, table, seq_lens, fmt, page_size=ps,
+        packed=packed, interpret=True))
+    want = np.asarray(pkv.paged_decode_attention_ref(
+        q, kc, ks, vc, vs, table, seq_lens, fmt, page_size=ps,
+        packed=packed))
+    np.testing.assert_array_equal(got[1], 0.0)
+    np.testing.assert_allclose(got[[0, 2]], want[[0, 2]], rtol=1e-5,
+                               atol=1e-5)
+
+
+def test_pages_per_block_covers_about_128_rows():
+    assert pkv.pages_per_block(16, 256) == 8
+    assert pkv.pages_per_block(16, 3) == 3            # never past Pmax
+    assert pkv.pages_per_block(4, 256) == 32
+    assert pkv.pages_per_block(256, 16) == 1
 
 
 def test_gather_pages_logical_order():
@@ -274,6 +336,32 @@ def test_engine_max_new_zero_reserves_first_append_page(smoke_model):
     assert req.done and len(req.out_tokens) == 1
     assert eng.allocator.live_pages == 0
     eng.allocator.assert_consistent()
+
+
+def test_engine_counts_live_and_table_pages_per_tick(smoke_model):
+    """Each decode tick adds the live pages of the active slots (their
+    length after this tick's append, in pages) to ``engine.kv_pages_live``
+    and the whole page table to ``engine.kv_pages_table``."""
+    cfg, params, prompts = smoke_model
+    ps, max_len = 4, 32
+    eng = ServingEngine(cfg, params,
+                        ServeConfig(max_batch=3, max_len=max_len,
+                                    kv_format="posit8", kv_layout="paged",
+                                    page_size=ps))
+    reqs = [Request(uid=i, prompt=p, max_new=8)
+            for i, p in enumerate(prompts[:2])]
+    assert all(eng.add_requests(reqs))
+    live = eng.metrics.counter("engine.kv_pages_live")
+    table = eng.metrics.counter("engine.kv_pages_table")
+    assert live.value == 0 and table.value == 0
+    lens = [len(p) for p in prompts[:2]]               # 4 and 11 tokens
+    want_live = 0
+    for tick in range(3):
+        eng.step()
+        want_live += sum(pages_for(n + tick + 1, ps) for n in lens)
+        assert live.value == want_live
+        assert table.value == (tick + 1) * 3 * pages_for(max_len, ps)
+    assert eng.stats["kv_pages_live"] == 5 + 6 + 6     # 2+3, 2+4, 2+4
 
 
 @pytest.mark.parametrize("kvf", ["bf16", "posit8"])

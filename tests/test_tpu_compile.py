@@ -3,7 +3,8 @@
 Interpret mode accepts block shapes and ops that Mosaic refuses, so the
 kernel-vs-reference tests alone cannot say whether the decode-on-read path
 runs on the chip.  Each test here lowers one kernel at paper-edge full
-widths (B=8, W=2048, 12 query / 4 KV heads, hd=64, page size 16) with
+widths (B=8, W=2048, 12 query / 4 KV heads, hd=64, page size 16; the
+paged decode attention at both benchmark cells' widths) with
 ``interpret=False`` for one chip of a described ``v5e:2x2`` topology and
 asserts that the compiled program holds the Mosaic kernel.
 
@@ -104,14 +105,26 @@ def test_paged_kv_append_rows_compiles(one_chip, fmt, packed):
              s((B, T), jnp.int32))
 
 
+# the benchmark's two paged cells: paper-edge (12 / 4 heads of 64) and
+# Granite-3.0-8B (32 / 8 heads of 128), 16 slots of 256 16-row pages
+ATTN_WIDTHS = [pytest.param(12, 4, 64, id="edge"),
+               pytest.param(32, 8, 128, id="granite")]
+
+
+@pytest.mark.parametrize("nh,nkv,hd", ATTN_WIDTHS)
 @pytest.mark.parametrize("fmt,packed", FMTS)
-def test_paged_decode_attention_compiles(one_chip, fmt, packed):
-    s, codes, scale = _pool_args(one_chip, fmt, packed)
+def test_paged_decode_attention_compiles(one_chip, fmt, packed, nh, nkv, hd):
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    b, pmax = 16, 256
+    rows = (1 + b * pmax) * PS
+    codes = s((rows, nkv, kvk.code_channels(hd, fmt, packed)),
+              fmt.storage_dtype)
+    scale = s((rows, nkv), jnp.float32)
     fn = lambda q, kc, ks, vc, vs, tbl, ln: pkv.paged_decode_attention(
         q, kc, ks, vc, vs, tbl, ln, fmt, page_size=PS, packed=packed,
         interpret=False)
-    _compile(fn, s((B, 1, NH, HD), jnp.bfloat16), codes, scale, codes,
-             scale, s((B, PMAX), jnp.int32), s((B,), jnp.int32))
+    _compile(fn, s((b, 1, nh, hd), jnp.bfloat16), codes, scale, codes,
+             scale, s((b, pmax), jnp.int32), s((b,), jnp.int32))
 
 
 def test_posit_matmul_compiles(one_chip):
