@@ -7,7 +7,10 @@ From the program it takes only the system under test (``ServingEngine``,
 ``Orchestrator``), its lifecycle stamps, its ``stage.*`` and ``engine.*``
 counters, and the names of its programs and kernels in the device trace.
 Everything else (traffic, weights, reference, operation and byte counts,
-peaks, the trace reduction) is the benchmark's own.
+peaks, the trace reduction) is the benchmark's own.  What depends on a
+model's architecture (its program config, weights, reference and work
+counts) comes from the module of its family, ``bench/families/<family>.py``,
+found by the name its configuration file gives.
 """
 from __future__ import annotations
 
@@ -30,12 +33,11 @@ ROOT = BENCH_DIR.parent
 sys.path.insert(0, str(BENCH_DIR))
 sys.path.insert(0, str(ROOT / "src"))
 
-import cost as cost_mod        # noqa: E402
 import metrics_common          # noqa: E402
-import reference as ref_mod    # noqa: E402
 import trace as trace_mod      # noqa: E402
 import traffic                 # noqa: E402
-import weights as weights_mod  # noqa: E402
+
+FAMILIES_DIR = BENCH_DIR / "families"
 
 DRAIN_S = 60.0          # how long past the close an answer due may take
 SAMPLE_REQUESTS = 8     # finished requests the reference checks: a fault
@@ -71,20 +73,43 @@ def load_spec(root: Path = ROOT) -> dict:
 
 
 def cell_parts(spec: dict, workload: str, traffic_dir: Path = None,
-               limits_dir: Path = None):
-    """(cell, config entry, config file, traffic mix, limits) of a cell,
-    each found by its name."""
+               limits_dir: Path = None, families_dir: Path = None):
+    """(cell, config entry, config file, family module, traffic mix,
+    limits) of a cell, each found by its name."""
     cells = {w["name"]: w for w in spec["workloads"]}
     if workload not in cells:
         raise KeyError(f"no workload {workload!r}; known: {sorted(cells)}")
     cell = cells[workload]
     entry = {c["name"]: c for c in spec["configs"]}[cell["config"]]
     conf = json.loads((ROOT / entry["file"]).read_text())
+    family = family_of(conf, families_dir)
     mix = traffic.load_mix(cell["traffic"],
                            traffic_dir or traffic.TRAFFIC_DIR)
     limits = json.loads(((limits_dir or BENCH_DIR / "limits")
                          / f"{workload}.json").read_text())
-    return cell, entry, conf, mix, limits
+    return cell, entry, conf, family, mix, limits
+
+
+def known_families(families_dir: Path = None) -> List[str]:
+    return sorted(p.stem for p in (families_dir or FAMILIES_DIR).glob("*.py"))
+
+
+def family_name(conf: dict) -> str:
+    """A configuration file's ``"family"``; ``dense`` where it names none."""
+    return conf.get("family", "dense")
+
+
+def family_of(conf: dict, families_dir: Path = None):
+    """The module ``bench/families/<family>.py`` of a configuration file's
+    family: its ``program_cfg``, ``make_weights``, ``make_reference`` and
+    ``make_cost`` (``bench/families/dense.py`` says what each gives)."""
+    directory = families_dir or FAMILIES_DIR
+    name = family_name(conf)
+    known = known_families(directory)
+    if name not in known:
+        raise KeyError(f"{conf['name']}: no family {name!r} in {directory}; "
+                       f"known: {known}")
+    return metrics_common.load_by_name(directory, name)
 
 
 def metric_reader(name: str):
@@ -96,36 +121,16 @@ def metric_reader(name: str):
 # building the served path
 # ---------------------------------------------------------------------------
 
-def model_cfg(conf: dict):
-    """The program's model config for ``conf``: the arch's published
-    widths, with the depth cut the file states; every width is checked
-    against the file."""
-    from repro.configs import get_config
-    m = conf["model"]
-    cfg = dataclasses.replace(
-        get_config(conf["arch"], smoke=conf.get("smoke", False)),
-        n_layers=m["num_hidden_layers"], dtype_name=conf["serving"]["dtype"])
-    want = {"d_model": m["hidden_size"], "n_heads": m["num_attention_heads"],
-            "n_kv_heads": m["num_key_value_heads"],
-            "head_dim": m["head_dim"], "d_ff": m["intermediate_size"],
-            "vocab": m["vocab_size"],
-            "tie_embed": m["tie_word_embeddings"],
-            "rope_theta": m["rope_theta"], "family": "dense",
-            "mlp": "swiglu"}
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        raise ValueError(f"{conf['name']}: program config {got} != {want}")
-    return cfg
-
-
-def build_engine(conf: dict, params, kv_format: Optional[str] = None):
+def build_engine(family, conf: dict, params,
+                 kv_format: Optional[str] = None):
     from repro.serve.engine import ServeConfig, ServingEngine
     s = conf["serving"]
     scfg = ServeConfig(max_batch=s["max_batch"], max_len=s["max_len"],
                        kv_format=kv_format or s["kv_format"],
                        kv_layout=s["kv_layout"], page_size=s["page_size"],
                        num_pages=s["num_pages"])
-    return ServingEngine(model_cfg(conf), params, scfg, policy=s["policy"])
+    return ServingEngine(family.program_cfg(conf), params, scfg,
+                         policy=s["policy"])
 
 
 class CompileCounter:
@@ -561,7 +566,7 @@ def check_width(mix) -> int:
     return -(-(hi + out) // 256) * 256
 
 
-def check(conf, params, w: Window, seed: int, closed: bool,
+def check(family, conf, params, w: Window, seed: int, closed: bool,
           limits: dict, mix: dict) -> Dict[str, dict]:
     """Every number compared, with its limit."""
     owed = w.recs if closed else w.due_in_window()
@@ -576,7 +581,7 @@ def check(conf, params, w: Window, seed: int, closed: bool,
         seqs = [np.concatenate([r.prompt, np.asarray(r.sreq.out_tokens[:-1],
                                                      np.int32)])
                 for r in sample]
-        gaps = ref_mod.make_reference(conf)(
+        gaps = family.make_reference(conf)(
             params, seqs, [len(r.prompt) for r in sample],
             served=[np.asarray(r.sreq.out_tokens, np.int32) for r in sample],
             width=check_width(mix))
@@ -674,19 +679,20 @@ def new_orchestrator(eng):
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              t_process: float, *, spec: Optional[dict] = None,
              kv_format: Optional[str] = None, fault: Optional[str] = None,
-             traffic_dir: Path = None, limits_dir: Path = None) -> dict:
+             traffic_dir: Path = None, limits_dir: Path = None,
+             families_dir: Path = None) -> dict:
     """One run of a cell: the result object the benchmark prints."""
     import jax
     spec = spec or load_spec()
-    cell, entry, conf, mix, limits = cell_parts(spec, workload, traffic_dir,
-                                                limits_dir)
+    cell, entry, conf, family, mix, limits = cell_parts(
+        spec, workload, traffic_dir, limits_dir, families_dir)
     closed = mix["loop"] == "closed"
     counter = CompileCounter.get()
     t0 = time.perf_counter()
-    params = weights_mod.make_weights(conf, seed)
+    params = family.make_weights(conf, seed)
     jax.block_until_ready(params)
     t_w = time.perf_counter()
-    eng = build_engine(conf, params, kv_format)
+    eng = build_engine(family, conf, params, kv_format)
     if fault:
         plant_fault(eng, fault)
     n_prog = warm(eng, mix)
@@ -718,14 +724,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     dev_extra, breakdown, per_layer = None, None, {}
     if trace:
         per_layer, dev_extra, breakdown = layer_metrics(
-            spec, workload, conf, w, rec, tracer)
+            spec, workload, family, conf, w, rec, tracer)
     device = device_info(dev_extra)
     values = e2e_values(w)
     att, failed = attempted_failed(w, closed)
     # free the program's state before the reference runs
     del orch, eng, rec, tracer
     gc.collect()
-    checks = check(conf, params, w, seed, closed, limits, mix)
+    checks = check(family, conf, params, w, seed, closed, limits, mix)
     if trace:
         metrics = per_layer
     else:
@@ -753,7 +759,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 class Ctx:
     """What a per-layer metric's reader may read."""
     conf: dict
-    cost: cost_mod.Cost
+    cost: Any                            # the family's make_cost(conf)
     peaks: dict
     window: Window
     trace: Optional[trace_mod.Trace]     # clipped to the traced window
@@ -772,7 +778,7 @@ def peaks_for(kind: str) -> dict:
     return table[kind]
 
 
-def layer_metrics(spec, workload, conf, w: Window, rec: Recorder,
+def layer_metrics(spec, workload, family, conf, w: Window, rec: Recorder,
                   tracer: Tracer):
     import jax
     tr = trace_mod.load(trace_mod.find_xplane(str(tracer.log_dir)))
@@ -783,7 +789,7 @@ def layer_metrics(spec, workload, conf, w: Window, rec: Recorder,
     t0, t1 = h0 + off, h1 + off
     clipped = tr.window(t0, t1)
     dev = tr.devices[0] if tr.devices else 0
-    ctx = Ctx(conf, cost_mod.Cost.from_config(conf),
+    ctx = Ctx(conf, family.make_cost(conf),
               peaks_for(jax.devices()[0].device_kind), w, clipped, dev,
               t0, t1, [g for g in rec.gen if h0 <= g[0] < h1],
               [p for p in rec.pre if h0 <= p[0] < h1])

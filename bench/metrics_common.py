@@ -1,8 +1,11 @@
-"""Helpers the per-layer metric readers share: loading a reader by its
-metric name from ``bench/metrics/<name>.py``, and the device idle share."""
+"""Helpers the per-layer metric readers and the model families share:
+loading a module by its name from a directory of the benchmark's own
+(``bench/metrics/<name>.py``, ``bench/families/<name>.py``), and the device
+idle share."""
 from __future__ import annotations
 
 import importlib.util
+import sys
 from pathlib import Path
 
 import trace as T
@@ -10,16 +13,28 @@ import trace as T
 METRICS_DIR = Path(__file__).resolve().parent / "metrics"
 
 
-def load_sibling(name: str):
-    """The reader module of metric ``name`` (names may hold dots)."""
-    path = METRICS_DIR / f"{name}.py"
+def load_by_name(directory: Path, name: str):
+    """The module ``<directory>/<name>.py`` (names may hold dots), loaded
+    by its path.  It is entered in ``sys.modules`` before it runs, as
+    ``dataclasses`` needs of a module that defines a dataclass."""
+    path = Path(directory) / f"{name}.py"
     if not path.is_file():
-        raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+        raise FileNotFoundError(f"no module {name!r} at {path}")
+    key = f"bench_{path.parent.name}_{name}"
+    spec = importlib.util.spec_from_file_location(key, path)
     mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    sys.modules[key] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        sys.modules.pop(key, None)
+        raise
     return mod
+
+
+def load_sibling(name: str):
+    """The reader module of metric ``name``."""
+    return load_by_name(METRICS_DIR, name)
 
 
 def idle_share(ctx):

@@ -97,14 +97,14 @@ def sweep(H, eng, mix, vocab, seed, seconds, rates):
     return rows, knee
 
 
-def read_seeds(H, conf, mix, limits, workload, kv_format, seed_list, seconds,
-               label, sweep_rates=None):
+def read_seeds(H, family, conf, mix, limits, workload, kv_format, seed_list,
+               seconds, label, sweep_rates=None):
     import jax
     import traffic
     closed = mix["loop"] == "closed"
     first = seed_list[0] if seed_list else 1
-    params = H.weights_mod.make_weights(conf, first)
-    eng = H.build_engine(conf, params, kv_format)
+    params = family.make_weights(conf, first)
+    eng = H.build_engine(family, conf, params, kv_format)
     n = H.warm(eng, mix)
     orch = H.new_orchestrator(eng)
     vocab = conf["model"]["vocab_size"]
@@ -119,7 +119,7 @@ def read_seeds(H, conf, mix, limits, workload, kv_format, seed_list, seconds,
         print(json.dumps({"rate_per_s": mix["rate_per_s"]}), flush=True)
     for seed in seed_list:
         eng.params = params = None          # one set of weights at a time
-        params = H.weights_mod.make_weights(conf, seed)
+        params = family.make_weights(conf, seed)
         jax.block_until_ready(params)
         eng.params = params
         H.reset_state(eng)
@@ -130,7 +130,8 @@ def read_seeds(H, conf, mix, limits, workload, kv_format, seed_list, seconds,
         w = H.drive(orch, eng, plan, mix, seconds, H.CompileCounter.get(),
                     more=stream)
         orch.close()
-        checks = H.check(conf, params, w, seed, closed, limits, mix)
+        checks = H.check(family, conf, params, w, seed, closed, limits,
+                         mix)
         row = {"workload": workload, "label": label, "kv_format": kv_format,
                "seed": seed, "correct": H.is_correct(checks),
                **{k: v for k, v in H.e2e_values(w).items()},
@@ -140,7 +141,7 @@ def read_seeds(H, conf, mix, limits, workload, kv_format, seed_list, seconds,
     return mix
 
 
-def witness(H, conf, kv_format, seed, n_req, max_new, layers):
+def witness(H, family, conf, kv_format, seed, n_req, max_new, layers):
     import reference as R
     import traffic
     from repro.serve.engine import Request
@@ -148,8 +149,8 @@ def witness(H, conf, kv_format, seed, n_req, max_new, layers):
     if layers:
         conf["model"]["num_hidden_layers"] = layers
     conf["serving"]["max_batch"] = n_req
-    params = H.weights_mod.make_weights(conf, seed)
-    eng = H.build_engine(conf, params, kv_format)
+    params = family.make_weights(conf, seed)
+    eng = H.build_engine(family, conf, params, kv_format)
     rng = traffic.rng_for(seed, 1)
     vocab = conf["model"]["vocab_size"]
     reqs = [Request(uid=i, prompt=rng.integers(0, vocab, int(n)).astype(
@@ -160,7 +161,8 @@ def witness(H, conf, kv_format, seed, n_req, max_new, layers):
     seqs = [np.concatenate([np.asarray(r.prompt, np.int32),
                             np.asarray(r.out_tokens[:-1], np.int32)])
             for r in reqs]
-    ref = R.make_reference(conf)(params, seqs, [len(r.prompt) for r in reqs])
+    ref = family.make_reference(conf)(params, seqs,
+                                      [len(r.prompt) for r in reqs])
     gaps = [float(R.served_gaps(lg, len(r.prompt),
                                 np.asarray(r.out_tokens)).max())
             for lg, r in zip(ref, reqs)]
@@ -190,13 +192,15 @@ def main():
     if args.witness:
         sys.path.insert(0, str(Path(__file__).resolve().parent))
         import harness as H
-        _, _, conf, _, _ = H.cell_parts(H.load_spec(), args.workload)
+        _, _, conf, family, _, _ = H.cell_parts(H.load_spec(),
+                                                args.workload)
         kv = conf["serving"]["kv_format"]
         n_req, max_new = (int(x) for x in args.witness.split(","))
         for fmt, group in ((kv, args.seeds), (LOWER_KV[kv],
                                                args.control_seeds)):
             for seed in seeds(group):
-                witness(H, conf, fmt, seed, n_req, max_new, args.layers)
+                witness(H, family, conf, fmt, seed, n_req, max_new,
+                        args.layers)
         return 0
     if jax.devices()[0].platform != "tpu":
         print("readings.py: needs a TPU", file=sys.stderr)
@@ -204,14 +208,15 @@ def main():
     sys.path.insert(0, str(Path(__file__).resolve().parent))
     import harness as H
     H.enable_compile_cache()
-    _, _, conf, mix, limits = H.cell_parts(H.load_spec(), args.workload)
+    _, _, conf, family, mix, limits = H.cell_parts(H.load_spec(),
+                                                   args.workload)
     kv = conf["serving"]["kv_format"]
     rates = [float(x) for x in args.sweep_rates.split(",") if x]
     if args.seeds or rates:
-        mix = read_seeds(H, conf, mix, limits, args.workload, kv,
+        mix = read_seeds(H, family, conf, mix, limits, args.workload, kv,
                          seeds(args.seeds), args.seconds, "program", rates)
     if args.control_seeds:
-        read_seeds(H, conf, mix, limits, args.workload, LOWER_KV[kv],
+        read_seeds(H, family, conf, mix, limits, args.workload, LOWER_KV[kv],
                    seeds(args.control_seeds), args.seconds, "control")
     return 0
 

@@ -27,18 +27,26 @@ def tiny_spec(cell, config="tiny.p8-paged"):
     return spec
 
 
+def limits_dir(config):
+    """A fixture's own limits, ``limits-<config>/``, where it has them.
+    The tied head's logits are small (a 0.02-scale embedding at width 64),
+    so its gaps are too: sound runs read 0.002-0.016 and the posit4 control
+    0.18-0.32 over seeds 1-8 and 11-16 on the CPU."""
+    own = FX / f"limits-{config}"
+    return own if own.is_dir() else FX / "limits"
+
+
 def run(cell, seed, config="tiny.p8-paged", seconds=2.0, **kw):
     return harness.run_cell(cell, seed, seconds, False, time.perf_counter(),
                             spec=tiny_spec(cell, config),
                             traffic_dir=FX / "traffic",
-                            limits_dir=FX / LIMITS[config], **kw)
+                            limits_dir=limits_dir(config), **kw)
 
 
-# The tied head's logits are small (a 0.02-scale embedding at width 64), so
-# its gaps are too: sound runs read 0.002-0.016 and the posit4 control
-# 0.18-0.32 over seeds 1-8 and 11-16 on the CPU.
-LIMITS = {"tiny.p8-paged": "limits", "tiny-tied.p8-paged": "limits-tied"}
-CONFIGS = list(LIMITS)
+# every fixture configuration whose family has a module
+CONFIGS = sorted(f.stem for f in FX.glob("*.json")
+                 if harness.family_name(json.loads(f.read_text()))
+                 in harness.known_families())
 
 
 @pytest.mark.parametrize("config", CONFIGS)
@@ -98,12 +106,11 @@ def test_closed_loop_ramp_admits_no_more_rows_than_warmed(monkeypatch):
     its first plan draws more from the seed's stream."""
     monkeypatch.setattr(harness, "WARM_ROWS", 1)
     spec = tiny_spec("tiny-closed")
-    _, _, conf, mix, _ = harness.cell_parts(spec, "tiny-closed",
-                                            FX / "traffic", FX / "limits")
+    _, _, conf, family, mix, _ = harness.cell_parts(
+        spec, "tiny-closed", FX / "traffic", FX / "limits")
     mix = dict(mix, clients=6, lead_s=0.0)   # all due at once
     vocab = conf["model"]["vocab_size"]
-    eng = harness.build_engine(conf, harness.weights_mod.make_weights(
-        conf, 21))
+    eng = harness.build_engine(family, conf, family.make_weights(conf, 21))
     harness.warm(eng, mix)
     orch = harness.new_orchestrator(eng)
     harness.warm_serve(orch, eng, mix, vocab)
@@ -122,12 +129,11 @@ def test_open_loop_holds_back_past_the_warmed_rows(monkeypatch):
     holds more rows than were warmed, and nothing compiles."""
     monkeypatch.setattr(harness, "WARM_ROWS", 1)
     spec = tiny_spec("tiny-open")
-    _, _, conf, mix, _ = harness.cell_parts(spec, "tiny-open",
-                                            FX / "traffic", FX / "limits")
+    _, _, conf, family, mix, _ = harness.cell_parts(
+        spec, "tiny-open", FX / "traffic", FX / "limits")
     mix = dict(mix, rate_per_s=400.0, lead_s=0.2)
     vocab = conf["model"]["vocab_size"]
-    eng = harness.build_engine(conf, harness.weights_mod.make_weights(
-        conf, 22))
+    eng = harness.build_engine(family, conf, family.make_weights(conf, 22))
     harness.warm(eng, mix)
     orch = harness.new_orchestrator(eng)
     harness.warm_serve(orch, eng, mix, vocab)

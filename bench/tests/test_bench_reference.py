@@ -10,9 +10,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
 import reference as R
 import weights as W
 from harness import BENCH_DIR
+from test_bench_correct import CONFIGS
 
 FX = BENCH_DIR / "tests" / "fixtures"
 TINY = json.loads((FX / "tiny.p8-paged.json").read_text())
@@ -102,25 +104,27 @@ def test_kv_rounding_matches_the_program_codec():
                                   np.asarray(want))
 
 
-@pytest.mark.parametrize("fixture", ["tiny.p8-paged", "tiny-tied.p8-paged"])
+@pytest.mark.parametrize("fixture", CONFIGS)
 def test_model_matches_the_program_forward(fixture):
     """With every query before ``n_prompt`` (full-precision K/V), the
-    reference is the program's float32 ``lm.forward`` under the policy,
-    with a head of its own and with the head tied to the embedding."""
+    family's reference is the program's float32 ``lm.forward`` under the
+    policy, on its weights: for the dense family with a head of its own
+    and with the head tied to the embedding."""
     from repro.core.transprecision import get_policy
     from repro.models import lm
-    import harness
     conf = json.loads((FX / f"{fixture}.json").read_text())
+    family = harness.family_of(conf)
     vocab = conf["model"]["vocab_size"]
-    w = W.make_weights(conf, 5)
-    cfg = dataclasses.replace(harness.model_cfg(conf), dtype_name="float32")
+    w = family.make_weights(conf, 5)
+    cfg = dataclasses.replace(family.program_cfg(conf), dtype_name="float32")
     w32 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), w)
     toks = np.random.default_rng(3).integers(0, vocab, (2, 40)) \
         .astype(np.int32)
+    policy = get_policy(conf["serving"]["policy"])
     with jax.default_matmul_precision("highest"):
         want = lm.forward(w32, {"tokens": jnp.asarray(toks)}, cfg,
-                          get_policy("paper_edge_p8"))[0][..., :vocab]
-    got = R.make_reference(conf)(w, list(toks), [40, 40])
+                          policy)[0][..., :vocab]
+    got = family.make_reference(conf)(w, list(toks), [40, 40])
     for g, wt in zip(got, np.asarray(want)):
         np.testing.assert_allclose(g, wt, rtol=2e-4, atol=2e-4)
 

@@ -62,12 +62,12 @@ def test_every_cell_reports_enough():
 
 @pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
 def test_cell_parts_resolve_by_name(cell):
-    w, entry, conf, mix, limits = harness.cell_parts(SPEC, cell)
+    w, entry, conf, family, mix, limits = harness.cell_parts(SPEC, cell)
     assert conf["name"] == w["config"] == entry["name"]
     assert sorted(conf["reduced"]) == sorted(entry["reduced"])
     assert mix["loop"] in ("open", "closed")
     assert limits["max_logit_gap"] > 0
-    harness.model_cfg(conf)              # the program agrees on every width
+    family.program_cfg(conf)             # the program agrees on every width
 
 
 def test_configs_are_files_of_their_own():
